@@ -3,7 +3,9 @@
 One ``ModelConfig`` per assigned architecture lives in
 ``src/repro/configs/<id>.py`` (exact public-literature dimensions) together
 with a reduced ``smoke()`` variant exercised by the CPU tests.  The FULL
-configs are touched only by the dry-run (ShapeDtypeStruct lowering).
+configs are lowered by the dry-run (ShapeDtypeStruct lowering); olmo-1b's
+also runs on a TPU through ``chip_smoke.py`` (serving at full depth,
+training with depth cut to 8 layers).
 """
 
 from __future__ import annotations
@@ -151,6 +153,11 @@ class ModelConfig:
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
+
+    def with_depth(self, n_layers: int) -> "ModelConfig":
+        """The first ``n_layers`` layers at unchanged widths (a depth cut)."""
+        return self.replace(n_layers=n_layers,
+                            layer_types=self.layer_types[:n_layers])
 
 
 @dataclass(frozen=True)

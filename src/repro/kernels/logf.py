@@ -2,11 +2,11 @@
 
 logf's distinguishing feature in the paper (Table I, ‡): its Type-1
 dependencies — table gathers at integer-computed indices — map to **ISSRs**.
-The TPU analogue is an in-kernel dynamic gather from a VMEM-resident table:
-the 16-entry invc/logc tables ride in as constant-index-map operands (one
-DMA, reused every block) and the integer phase's index vector drives a
-lane-wise ``jnp.take``.  On the VPU a 16-entry gather lowers to a one-hot
-select tree — cheap because the table fits a single vreg.
+The TPU analogue is an in-kernel lookup in a table held in scalar memory:
+the 16-entry invc/logc tables ride in as whole-array SMEM operands (one
+copy, reused every block) and the integer phase's index vector drives a
+16-way select, one ``where`` per table entry.  Mosaic lowers no 1-D
+vector gather, and the select costs 16 VPU ops per vreg.
 
 Phase structure: INT₀ (bit manipulation: re-bias, window index, exponent
 extraction, mantissa masking) → [ISSR gather] → FP₁ (r = z·invc − 1,
@@ -23,6 +23,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ref import (LOGF_INVC, LOGF_LOGC, _LN2, _LOG1P_POLY,
                                _LOGF_OFF, _LOGF_TABLE_BITS)
@@ -42,8 +43,12 @@ def _log_kernel(x_ref, invc_ref, logc_ref, o_ref):
     iz = ix - (tmp & jnp.int32(np.int32(np.uint32(0xff800000))))
     z = jax.lax.bitcast_convert_type(iz, jnp.float32)
     # --- ISSR: indirect streams invc[i], logc[i] driven by the index vector.
-    invc = jnp.take(invc_ref[...], i, axis=0)
-    logc = jnp.take(logc_ref[...], i, axis=0)
+    invc = jnp.zeros_like(z)
+    logc = jnp.zeros_like(z)
+    for j in range(1 << _LOGF_TABLE_BITS):
+        hit = i == j
+        invc = jnp.where(hit, invc_ref[j], invc)
+        logc = jnp.where(hit, logc_ref[j], logc)
     # --- FP phase 1.
     r = z * invc - jnp.float32(1.0)
     p = jnp.full_like(r, _LOG1P_POLY[0])
@@ -59,16 +64,13 @@ def log_2d(x: jax.Array, block_rows: int = DEFAULT_BLOCK_ROWS,
     """ln over a (rows, LANES) fp32 array of positive normals."""
     rows, lanes = x.shape
     assert lanes == LANES and rows % block_rows == 0, (x.shape, block_rows)
-    n_table = 1 << _LOGF_TABLE_BITS
+    table = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
         _log_kernel,
         out_shape=jax.ShapeDtypeStruct(x.shape, jnp.float32),
         grid=(rows // block_rows,),
-        in_specs=[
-            pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((n_table,), lambda i: (0,)),   # table: constant map
-            pl.BlockSpec((n_table,), lambda i: (0,)),
-        ],
+        in_specs=[pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
+                  table, table],
         out_specs=pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
         interpret=interpret,
     )(x.astype(jnp.float32), LOGF_INVC, LOGF_LOGC)

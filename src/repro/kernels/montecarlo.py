@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.prng import _splitmix32
+from repro.kernels.prng import _splitmix32, _to_unit
 from repro.kernels.ref import LCG_A, LCG_C, MC_POLY_COEFFS
 
 LANES = 1024
@@ -60,10 +60,6 @@ def _step(kind: str, state):
     s2 = s2 ^ t
     s3 = (s3 << jnp.uint32(11)) | (s3 >> jnp.uint32(21))
     return (s0, s1, s2, s3), out
-
-
-def _to_unit(bits):
-    return (bits >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(2.0 ** -24)
 
 
 def _poly_eval(x):
@@ -97,7 +93,7 @@ def _mc_kernel(seed_ref, o_ref, *, kind: str, problem: str, iters: int):
         return state, accs
 
     _, accs = jax.lax.fori_loop(0, iters, body, (state, accs))
-    o_ref[...] = (accs[0] + accs[1] + accs[2]).reshape(1, LANES)
+    o_ref[...] = (accs[0] + accs[1] + accs[2]).reshape(1, 1, LANES)
 
 
 @functools.partial(jax.jit,
@@ -105,17 +101,23 @@ def _mc_kernel(seed_ref, o_ref, *, kind: str, problem: str, iters: int):
                                     "interpret"))
 def mc_partial_sums(seed: jax.Array, *, kind: str, problem: str, iters: int,
                     n_blocks: int, interpret: bool = False) -> jax.Array:
-    """Per-block hit counts, shape (n_blocks, LANES)."""
+    """Per-block hit counts, shape (n_blocks, LANES).
+
+    Each grid step writes a (1, 1, LANES) block of an (n_blocks, 1, LANES)
+    array: a (1, LANES) block of an (n_blocks, LANES) array breaks the TPU
+    rule that the second-to-last block dim divides by 8 or spans the
+    array."""
     kern = functools.partial(_mc_kernel, kind=kind, problem=problem,
                              iters=iters)
-    return pl.pallas_call(
+    sums = pl.pallas_call(
         kern,
-        out_shape=jax.ShapeDtypeStruct((n_blocks, LANES), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n_blocks, 1, LANES), jnp.float32),
         grid=(n_blocks,),
         in_specs=[pl.BlockSpec((1,), lambda i: (0,))],
-        out_specs=pl.BlockSpec((1, LANES), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((1, 1, LANES), lambda i: (i, 0, 0)),
         interpret=interpret,
     )(jnp.asarray([seed], jnp.uint32).reshape(1))
+    return sums.reshape(n_blocks, LANES)
 
 
 def mc_estimate(seed: int, *, kind: str, problem: str, n_samples: int,

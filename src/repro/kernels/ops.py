@@ -34,6 +34,7 @@ from repro.kernels import montecarlo as _mc
 from repro.kernels import prng as _prng
 from repro.kernels import ref as _ref
 from repro.kernels import softmax_tpu as _softmax
+from repro.parallel import autoshard
 
 LANES = _exp.LANES
 
@@ -196,20 +197,29 @@ def log(x: jax.Array, impl: str | None = None,
 def softmax(x: jax.Array, axis: int = -1, impl: str | None = None,
             block_rows: int | None = None) -> jax.Array:
     """COPIFT softmax.  Pallas path: 2-D row-tiled kernel over the last
-    axis; other axes / ragged rows fall back to the reference path."""
-    if _resolve(impl) == "reference" or axis not in (-1, x.ndim - 1):
+    axis (another axis is moved last and back), rows zero-padded to a
+    multiple of the (8-aligned) block.  Rows longer than the kernel's VMEM
+    limit raise ``ValueError``."""
+    if _resolve(impl) == "reference":
         return _ref.softmax_ref(x, axis=axis)
-    block_rows = _resolve_rows("softmax", block_rows, 8)
-    lead = x.shape[:-1]
-    rows = int(np.prod(lead)) if lead else 1
+    if axis not in (-1, x.ndim - 1):
+        y = softmax(jnp.moveaxis(x, axis, -1), -1, impl, block_rows)
+        return jnp.moveaxis(y, -1, axis)
     cols = x.shape[-1]
-    x2 = x.reshape(rows, cols)
-    br = block_rows
-    while rows % br:
-        br //= 2
-    br = max(br, 1)
-    y = _softmax.softmax_2d(x2, block_rows=br, interpret=_interpret())
-    return y.reshape(x.shape)
+    # TPU blocks span a multiple of 8 rows; shrink toward 8 for long rows.
+    br = _resolve_rows("softmax", block_rows, 8)
+    br = max(8, min(br, _softmax.MAX_BLOCK_ELEMS // cols) // 8 * 8)
+    interpret = _interpret()
+
+    def rows_softmax(xs):
+        x2 = xs.reshape(-1, cols)
+        rows = x2.shape[0]
+        x2 = jnp.pad(x2, ((0, -rows % br), (0, 0)))
+        y = _softmax.softmax_2d(x2, block_rows=br, interpret=interpret)
+        return y[:rows].reshape(xs.shape)
+
+    lead = x if x.ndim >= 2 else x.reshape(1, cols)   # rows on a leading dim
+    return autoshard.rowwise(rows_softmax, lead).reshape(x.shape)
 
 
 def uniform(seed: int | jax.Array, shape: tuple[int, ...],

@@ -43,6 +43,14 @@ def _splitmix32(z):
     return z ^ (z >> jnp.uint32(16))
 
 
+def _to_unit(bits):
+    """uint32 → fp32 in [0, 1) from the top 24 bits.  Mosaic has no
+    uint32 → float32 cast; the value is below 2**24, so going through
+    int32 is exact."""
+    top = (bits >> jnp.uint32(8)).astype(jnp.int32)
+    return top.astype(jnp.float32) * jnp.float32(2.0 ** -24)
+
+
 def _uniform_kernel(seed_ref, o_ref, *, kind: str, block_rows: int):
     # INT phase: global element counter → per-lane stream seed → one step.
     b = pl.program_id(0)
@@ -61,7 +69,7 @@ def _uniform_kernel(seed_ref, o_ref, *, kind: str, block_rows: int):
         s3 = _splitmix32(idx + jnp.uint32((3 * 0x9e3779b9) & 0xffffffff))
         bits = s0 + s3
     # FP phase: top-24-bit conversion to [0, 1).
-    o_ref[...] = (bits >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(2.0 ** -24)
+    o_ref[...] = _to_unit(bits)
 
 
 @functools.partial(jax.jit, static_argnames=("rows", "kind", "block_rows",
@@ -99,4 +107,4 @@ def uniform_counter_ref(seed: int, shape: tuple[int, int],
         s0 = _splitmix32(idx)
         s3 = _splitmix32(idx + jnp.uint32((3 * 0x9e3779b9) & 0xffffffff))
         bits = s0 + s3
-    return (bits >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(2.0 ** -24)
+    return _to_unit(bits)

@@ -7,12 +7,14 @@ assembly → FP polynomial) inside a numerically-stable row softmax, and is
 what ``repro.models`` attention uses when ``use_copift_softmax`` is set.
 
 Tiling: grid over row blocks; each grid step holds (block_rows, cols) in
-VMEM — cols up to 32 k fp32 (128 KiB/row-block-slice) stays comfortably
-inside VMEM for block_rows ≤ 32.  Row-internal reductions (max/sum) run on
-the VPU; the three COPIFT phases of the exp are as in ``exp.py``.
+VMEM.  Row-internal reductions (max/sum) run on the VPU; the three COPIFT
+phases of the exp are as in ``expf.py``.  A block may hold at most
+``MAX_BLOCK_ELEMS`` elements: on a TPU v5e, 8 rows × 65 536 fp32 columns
+compile and 8 × 131 072 exceed the scoped VMEM.  Longer rows raise
+``ValueError``; there is no fallback path.
 
-For rows longer than VMEM allows, ``ops.softmax`` falls back to a two-pass
-chunked jnp path (same math) — documented, not silent.
+The backward pass is ``y * (g - Σ g·y)`` in plain jnp (``jax.custom_vjp``),
+so ``jax.grad`` goes through the kernel's forward.
 """
 
 from __future__ import annotations
@@ -48,12 +50,13 @@ def _softmax_kernel(x_ref, o_ref):
     o_ref[...] = (e / jnp.sum(e, axis=-1, keepdims=True)).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def softmax_2d(x: jax.Array, block_rows: int = 8,
-               interpret: bool = False) -> jax.Array:
-    """Row softmax over (rows, cols); rows % block_rows == 0."""
+#: Elements one grid step may hold (8 rows × 64 k fp32 columns).
+MAX_BLOCK_ELEMS = 8 * 65536
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _softmax_rows(x, block_rows, interpret):
     rows, cols = x.shape
-    assert rows % block_rows == 0, (x.shape, block_rows)
     return pl.pallas_call(
         _softmax_kernel,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
@@ -62,3 +65,33 @@ def softmax_2d(x: jax.Array, block_rows: int = 8,
         out_specs=pl.BlockSpec((block_rows, cols), lambda i: (i, 0)),
         interpret=interpret,
     )(x)
+
+
+def _softmax_rows_fwd(x, block_rows, interpret):
+    y = _softmax_rows(x, block_rows, interpret)
+    return y, y
+
+
+def _softmax_rows_bwd(block_rows, interpret, y, g):
+    yf, gf = y.astype(jnp.float32), g.astype(jnp.float32)
+    dx = yf * (gf - jnp.sum(gf * yf, axis=-1, keepdims=True))
+    return (dx.astype(y.dtype),)
+
+
+_softmax_rows.defvjp(_softmax_rows_fwd, _softmax_rows_bwd)
+
+
+@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
+def softmax_2d(x: jax.Array, block_rows: int = 8,
+               interpret: bool = False) -> jax.Array:
+    """Row softmax over (rows, cols); rows % block_rows == 0."""
+    rows, cols = x.shape
+    if rows % block_rows:
+        raise ValueError(f"rows={rows} is not a multiple of "
+                         f"block_rows={block_rows}")
+    if block_rows * cols > MAX_BLOCK_ELEMS:
+        raise ValueError(
+            f"a ({block_rows}, {cols}) softmax block exceeds the kernel's "
+            f"VMEM limit of {MAX_BLOCK_ELEMS} elements (rows of at most "
+            f"{MAX_BLOCK_ELEMS // 8} columns at 8 rows per block)")
+    return _softmax_rows(x, block_rows, interpret)

@@ -29,89 +29,17 @@ import time
 import traceback
 
 import jax
-import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import SHAPES, applicable_shapes, load_config
 from repro.configs.registry import ARCHS
 from repro.launch import specs as SP
-from repro.launch.mesh import make_production_mesh, mesh_context
-from repro.models.model import forward
-from repro.parallel.autoshard import activation_sharding
+from repro.launch.mesh import make_production_mesh
 from repro.parallel.sharding import ShardingRules
-from repro.serve.engine import make_serve_step
-from repro.train.optimizer import AdamWConfig
-from repro.train.train_step import make_train_step
 
 OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                        "experiments", "dryrun")
 
 from repro.launch.hlo_analysis import collective_bytes
-
-
-def _step_and_specs(cfg, shape, rules: ShardingRules, mesh):
-    """Returns (fn, args tuple of ShapeDtypeStructs, in_shardings tuple)."""
-    ns = lambda tree: jax.tree.map(
-        lambda s: NamedSharding(mesh, s), tree,
-        is_leaf=lambda x: isinstance(x, P))
-    seq_sharded = tuple(rules.batch_spec(shape)) [0] is None and \
-        tuple(rules.batch_spec(shape))[1] is not None
-
-    def with_ctx(fn):
-        import functools
-
-        @functools.wraps(fn)
-        def wrapped(*a, **kw):
-            with activation_sharding(
-                    mesh, dp=rules.dp_axes,
-                    tp="model" if rules.use_tp else None,
-                    seq_sharded=seq_sharded):
-                return fn(*a, **kw)
-        return wrapped
-
-    if shape.kind == "decode":
-        sp = SP.decode_specs(cfg, shape)
-        step = with_ctx(make_serve_step(cfg))
-        in_sh = (ns(rules.params_pspecs(sp["params"])),
-                 ns(rules.cache_pspecs(sp["cache"], shape)),
-                 NamedSharding(mesh, rules.batch_spec(shape)
-                               if shape.global_batch > 1 else P(None, None)),
-                 NamedSharding(mesh, P()))
-        args = (sp["params"], sp["cache"], sp["tokens"], sp["cache_index"])
-        return step, args, in_sh
-
-    if shape.kind == "prefill":
-        sp = {"params": SP.params_specs(cfg),
-              "batch": SP.batch_specs(cfg, shape)}
-
-        def prefill_step(params, batch):
-            logits, _, _ = forward(params, cfg, batch, logits_mode="last")
-            return logits[:, 0]
-
-        in_sh = (ns(rules.params_pspecs(sp["params"])),
-                 jax.tree.map(lambda _: NamedSharding(
-                     mesh, rules.batch_spec(shape)), sp["batch"]))
-        return with_ctx(prefill_step), (sp["params"], sp["batch"]), in_sh
-
-    # train
-    sp = SP.input_specs(cfg, shape)
-    opt_cfg = AdamWConfig()
-    step = with_ctx(make_train_step(cfg, opt_cfg))
-    state_pspecs = {
-        "params": rules.params_pspecs(sp["state"]["params"]),
-        "opt": {"m": rules.params_pspecs(sp["state"]["opt"]["m"]),
-                "v": rules.params_pspecs(sp["state"]["opt"]["v"]),
-                "step": P()},
-    }
-    bspec = rules.batch_spec(shape)
-
-    def batch_sh(leaf):
-        nd = len(leaf.shape)
-        spec = bspec if nd == 2 else P(*(tuple(bspec) + (None,) * (nd - 2)))
-        return NamedSharding(mesh, spec)
-
-    in_sh = (ns(state_pspecs), jax.tree.map(batch_sh, sp["batch"]))
-    return step, (sp["state"], sp["batch"]), in_sh
 
 
 def run_cell(arch: str, shape_name: str, mesh_kind: str) -> dict:
@@ -124,8 +52,8 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str) -> dict:
                   n_params=cfg.n_params(),
                   n_active_params=cfg.n_active_params())
     t0 = time.time()
-    fn, args, in_sh = _step_and_specs(cfg, shape, rules, mesh)
-    with mesh_context(mesh):
+    fn, args, in_sh = SP.step_and_specs(cfg, shape, rules, mesh)
+    with jax.set_mesh(mesh):
         lowered = jax.jit(fn, in_shardings=in_sh).lower(*args)
         record["lower_s"] = round(time.time() - t0, 1)
         t1 = time.time()

@@ -3,38 +3,13 @@
 A FUNCTION, not a module-level constant — importing this module never
 touches jax device state (the dry-run pins the device count via XLA_FLAGS
 before any jax import; tests and benches must keep seeing 1 device).
-
-Version compatibility: ``jax.sharding.AxisType`` (and ``jax.make_mesh``'s
-``axis_types`` kwarg) only exist on newer JAX releases, and ``jax.set_mesh``
-replaced the ``with mesh:`` context manager.  Both are guarded here so the
-same call sites work on 0.4.x and 0.5+.
+Meshes use ``AxisType.Auto`` axes; enter one with ``jax.set_mesh(mesh)``.
 """
 
 from __future__ import annotations
 
 import jax
-
-
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types=(AxisType.Auto, ...)`` where supported, else nothing.
-
-    Older JAX (≤0.4.x) has neither ``jax.sharding.AxisType`` nor the
-    ``axis_types`` parameter on ``jax.make_mesh``; the default behavior
-    there matches Auto, so omitting the kwarg is the correct fallback.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
-def mesh_context(mesh: jax.sharding.Mesh):
-    """``jax.set_mesh(mesh)`` where it exists, else the legacy ``with mesh:``
-    context manager (valid on 0.4.x, where Mesh is itself a context)."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -42,9 +17,9 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     leading "pod" (pure-DP) axis crossing the inter-pod DCI."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> jax.sharding.Mesh:
     """Arbitrary mesh (tests use small host-device meshes)."""
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))

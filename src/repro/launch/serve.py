@@ -15,6 +15,7 @@ import numpy as np
 import jax
 
 from repro.configs import load_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import init_params
 from repro.serve.engine import ServeEngine
 from repro.train import checkpoint as ckpt
@@ -32,6 +33,7 @@ def main(argv=None):
     ap.add_argument("--params", default="", help="optional checkpoint path")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = load_config(args.arch, args.variant)
     if cfg.is_encoder_only:
         raise SystemExit(f"{cfg.name} is encoder-only: no decode step "

@@ -9,8 +9,13 @@ Laptop-scale run (the examples use this):
   PYTHONPATH=src python -m repro.launch.train --arch olmo-1b --variant smoke \
       --steps 50 --batch 8 --seq 128 --ckpt-dir /tmp/ckpt
 
-Cluster-scale invocations keep the same flags plus --mesh data,model=...;
-on this CPU container meshes beyond 1 device are exercised via the dry-run.
+One TPU v5e at OLMo-1B widths, depth cut to 8 layers so the state fits:
+  PYTHONPATH=src python -m repro.launch.train --variant full --layers 8 \
+      --steps 3 --batch 8 --seq 512
+
+This script trains on one device.  The sharded step (``ShardingRules`` on a
+mesh) is lowered by ``repro.launch.dryrun`` and run on four chips by
+``chip_smoke.py --chips 4``.
 """
 
 from __future__ import annotations
@@ -28,16 +33,29 @@ import jax.numpy as jnp
 from repro.configs import SHAPES, load_config
 from repro.configs.base import ShapeConfig
 from repro.data.pipeline import PipelineConfig, TokenPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import init_params
 from repro.train.fault import CheckpointManager, StragglerMonitor
 from repro.train.optimizer import AdamWConfig
 from repro.train.train_step import init_train_state, make_train_step
 
 
+def jit_train_step(cfg, opt_cfg: AdamWConfig, n_microbatches: int = 1):
+    """``make_train_step`` jitted with its state donated: the output state
+    reuses the input's buffers, so only one copy of params + optimizer
+    state is live."""
+    return jax.jit(make_train_step(cfg, opt_cfg, n_microbatches=n_microbatches),
+                   donate_argnums=0)
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo-1b")
     ap.add_argument("--variant", choices=["full", "smoke"], default="smoke")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="keep only the first N layers (0: all)")
+    ap.add_argument("--softmax-impl", choices=["auto", "pallas", "reference"],
+                    default="auto")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -59,13 +77,18 @@ def main(argv=None):
         print("[tune] kernel block tilings autotuned "
               "(repro.api.default_tuner cache)")
 
-    cfg = load_config(args.arch, args.variant)
+    enable_compile_cache()
+    cfg = load_config(args.arch, args.variant).replace(
+        softmax_impl=args.softmax_impl)
+    if args.layers:
+        print(f"[config] {cfg.name}: depth cut to {args.layers} of "
+              f"{cfg.n_layers} layers, widths unchanged")
+        cfg = cfg.with_depth(args.layers)
     shape = ShapeConfig("cli", args.seq, args.batch, "train")
     pipe = TokenPipeline(cfg, shape, PipelineConfig(seed=args.seed + 1))
     opt_cfg = AdamWConfig(lr=args.lr, total_steps=args.steps,
                           warmup_steps=max(1, args.steps // 10))
-    step_fn = jax.jit(make_train_step(cfg, opt_cfg,
-                                      n_microbatches=args.microbatches))
+    step_fn = jit_train_step(cfg, opt_cfg, args.microbatches)
 
     def init_fn():
         params = init_params(cfg, jax.random.PRNGKey(args.seed))

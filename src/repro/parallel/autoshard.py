@@ -11,6 +11,7 @@ context every call is a no-op — single-device tests never see a mesh.
 
 from __future__ import annotations
 
+import math
 import threading
 from contextlib import contextmanager
 from dataclasses import dataclass
@@ -105,21 +106,6 @@ def logits(x):
     return x
 
 
-@jax.custom_jvp
-def _diffable_barrier(x):
-    # Older JAX releases ship no differentiation rule for
-    # optimization_barrier; the barrier is an XLA scheduling hint, so the
-    # identity JVP below is exact and keeps remat'd training steps
-    # differentiable on every supported version.
-    return jax.lax.optimization_barrier(x)
-
-
-@_diffable_barrier.defjvp
-def _diffable_barrier_jvp(primals, tangents):
-    (x,), (t,) = primals, tangents
-    return _diffable_barrier(x), t
-
-
 def barrier(x):
     """Optimization barrier under an active mesh context: pins the bf16
     downcast on the producer side of SPMD-inserted collectives (XLA's CPU
@@ -127,7 +113,7 @@ def barrier(x):
     TP partial-sum reduction into fp32 — 2× the ICI traffic).  §Perf it.2."""
     if current() is None:
         return x
-    return _diffable_barrier(x)
+    return jax.lax.optimization_barrier(x)
 
 
 def tokens_nd(x):
@@ -140,3 +126,24 @@ def tokens_nd(x):
     if x.shape[0] % _dp_size(ctx) == 0:
         return _constrain(x, P(ctx.dp, *([None] * (x.ndim - 1))))
     return x
+
+
+def rowwise(fn, x):
+    """``fn(x)`` for a kernel that treats each index of x's leading dim on
+    its own.  Mosaic kernels cannot be partitioned automatically, so under
+    a multi-device mesh (``jax.set_mesh``) ``fn`` runs in a ``shard_map``
+    that splits the leading dim over the largest prefix of the
+    data-parallel axes dividing it (replicated over the mesh if none
+    does)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return fn(x)
+    ctx = current()
+    lead = ()
+    for a in ctx.dp if ctx is not None else mesh.axis_names:
+        if x.shape[0] % math.prod(mesh.shape[b] for b in lead + (a,)):
+            break
+        lead += (a,)
+    spec = P(lead or None, *([None] * (x.ndim - 1)))
+    return jax.shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec,
+                         check_vma=False)(x)

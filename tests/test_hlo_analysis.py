@@ -73,7 +73,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.launch.hlo_analysis import collective_bytes
-from repro.launch.mesh import make_mesh, mesh_context
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((4,), ("d",))
 TRIPS = 7
 def fn(x):
@@ -86,7 +86,7 @@ def fn(x):
         return y / jnp.float32(64.0), None
     out, _ = jax.lax.scan(body, x, None, length=TRIPS)
     return out
-with mesh_context(mesh):
+with jax.set_mesh(mesh):
     comp = jax.jit(fn).lower(
         jax.ShapeDtypeStruct((64, 64), jnp.float32,
                              sharding=NamedSharding(mesh, P("d", None)))

@@ -121,15 +121,16 @@ import jax
 from repro.configs import load_config, SHAPES
 from repro.configs.base import ShapeConfig
 from repro.parallel.sharding import ShardingRules
-from repro.launch.mesh import make_mesh, mesh_context
-from repro.launch.dryrun import _step_and_specs, collective_bytes
+from repro.launch.mesh import make_mesh
+from repro.launch.hlo_analysis import collective_bytes
+from repro.launch.specs import step_and_specs
 
 cfg = load_config("olmo-1b", "smoke").replace(remat="full")
 shape = ShapeConfig("t", 256, 8, "train")
 mesh = make_mesh((4, 2), ("data", "model"))
 rules = ShardingRules(cfg, mesh)
-fn, args, in_sh = _step_and_specs(cfg, shape, rules, mesh)
-with mesh_context(mesh):
+fn, args, in_sh = step_and_specs(cfg, shape, rules, mesh)
+with jax.set_mesh(mesh):
     compiled = jax.jit(fn, in_shardings=in_sh).lower(*args).compile()
 cb = collective_bytes(compiled.as_text())
 assert sum(cb["counts"].values()) > 0, "sharded step must communicate"

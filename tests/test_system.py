@@ -2,15 +2,25 @@
 learns on the synthetic stream, the serving engine decodes coherently, and
 the benchmark harness produces every paper table."""
 
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from repro.configs import load_config
+from repro.launch import compile_cache
 from repro.launch import train as train_mod
 from repro.models.model import init_params
 from repro.serve.engine import ServeEngine
+from repro.train.optimizer import AdamWConfig
+from repro.train.train_step import init_train_state
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 class TestTrainSystem:
@@ -30,6 +40,54 @@ class TestTrainSystem:
         h2 = train_mod.main(["--arch", "olmo-1b", "--variant", "smoke",
                              "--steps", "5", "--batch", "4", "--seq", "64"])
         assert [x["loss"] for x in h1] == [x["loss"] for x in h2]
+
+
+    def test_train_step_donates_state(self):
+        """Only one copy of params + optimizer state is live: the step's
+        input state is donated to its output."""
+        cfg = load_config("olmo-1b", "smoke")
+        state = init_train_state(cfg, init_params(cfg, jax.random.PRNGKey(0)))
+        batch = {"tokens": jnp.zeros((2, 16), jnp.int32)}
+        new, _ = train_mod.jit_train_step(cfg, AdamWConfig())(state, batch)
+        assert all(leaf.is_deleted() for leaf in jax.tree.leaves(state))
+        assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(new))
+
+    def test_depth_cut_keeps_widths(self, capsys):
+        history = train_mod.main(["--arch", "olmo-1b", "--variant", "smoke",
+                                  "--layers", "1", "--steps", "1",
+                                  "--batch", "2", "--seq", "16"])
+        assert np.isfinite(history[0]["loss"])
+        assert "depth cut to 1 of 2 layers" in capsys.readouterr().out
+
+
+class TestCompileCache:
+    def test_default_is_a_fixed_dir_in_the_checkout(self, monkeypatch):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        prev = jax.config.jax_compilation_cache_dir
+        try:
+            compile_cache.enable_compile_cache()
+            assert (jax.config.jax_compilation_cache_dir
+                    == os.path.join(REPO, ".jax_cache"))
+        finally:
+            jax.config.update("jax_compilation_cache_dir", prev)
+
+    def test_env_dir_is_used_and_nothing_else(self, tmp_path):
+        """With JAX_COMPILATION_CACHE_DIR set, train.main sets nothing and
+        its compiles land in that directory."""
+        script = (
+            "import jax\n"
+            "from repro.launch import train\n"
+            "train.main(['--variant', 'smoke', '--steps', '1', '--batch', "
+            "'2', '--seq', '16'])\n"
+            "print('DIR', jax.config.jax_compilation_cache_dir)\n")
+        env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+                   JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+                   JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+        r = subprocess.run([sys.executable, "-c", script], cwd=REPO, env=env,
+                           capture_output=True, text=True, timeout=240)
+        assert r.returncode == 0, r.stderr
+        assert f"DIR {tmp_path}" in r.stdout
+        assert any(tmp_path.iterdir())
 
 
 class TestServeSystem:
