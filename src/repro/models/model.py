@@ -62,9 +62,10 @@ def _positions(cfg: ModelConfig, batch: dict, B: int, T_len: int,
 
 def _readout(params, cfg: ModelConfig, x):
     dt = jnp.dtype(cfg.dtype)
-    if cfg.tie_embeddings:
-        return L.unembed(params["embed"], x, dt)
-    return L.linear(params["head"], x, dt)
+    with jax.named_scope("readout"):
+        if cfg.tie_embeddings:
+            return L.unembed(params["embed"], x, dt)
+        return L.linear(params["head"], x, dt)
 
 
 def _cast_once(params, cfg: ModelConfig):
@@ -75,10 +76,11 @@ def _cast_once(params, cfg: ModelConfig):
     dt = jnp.dtype(cfg.dtype)
     if jnp.dtype(cfg.param_dtype) == dt:
         return params
-    return jax.tree.map(
-        lambda p: p.astype(dt) if (p.ndim >= 2 and
-                                   p.dtype == jnp.dtype(cfg.param_dtype))
-        else p, params)
+    with jax.named_scope("weight_cast"):
+        return jax.tree.map(
+            lambda p: p.astype(dt) if (p.ndim >= 2 and
+                                       p.dtype == jnp.dtype(cfg.param_dtype))
+            else p, params)
 
 
 def forward(params, cfg: ModelConfig, batch: dict, cache=None,
@@ -86,22 +88,29 @@ def forward(params, cfg: ModelConfig, batch: dict, cache=None,
     """returns (logits, new_cache, aux_loss).
 
     logits_mode: "all" (B,T,V) | "last" (B,1,V — decode/prefill readout) |
-    "hidden" (B,T,D — the chunked-CE loss path reads out itself)."""
+    "hidden" (B,T,D — the chunked-CE loss path reads out itself).
+
+    Named scopes (``weight_cast``, ``embed``, ``layer_scan``, ``norm``,
+    ``attention``, ``ffn``, ``moe``, ``ssm``, ``readout``, and ``loss`` and
+    ``optimizer`` in training) mark each op's model part in the HLO
+    metadata, which a device trace carries."""
     params = _cast_once(params, cfg)
     dt = jnp.dtype(cfg.dtype)
-    if cfg.frontend == "audio":
-        x = batch["embeds"].astype(dt)
-    else:
-        x = L.embed(params["embed"], batch["tokens"], dt)
-        if cfg.embed_scale:
-            x = x * jnp.asarray(cfg.d_model ** 0.5, dt)
-    x = autoshard.hidden(x)
+    with jax.named_scope("embed"):
+        if cfg.frontend == "audio":
+            x = batch["embeds"].astype(dt)
+        else:
+            x = L.embed(params["embed"], batch["tokens"], dt)
+            if cfg.embed_scale:
+                x = x * jnp.asarray(cfg.d_model ** 0.5, dt)
+        x = autoshard.hidden(x)
     B, T_len = x.shape[:2]
     positions = _positions(cfg, batch, B, T_len, cache_index)
 
     x, new_cache, aux = T.apply_stack(params["stack"], cfg, x, positions,
                                       cache, cache_index)
-    x = L.norm(cfg.norm, params["final_norm"], x)
+    with jax.named_scope("norm"):
+        x = L.norm(cfg.norm, params["final_norm"], x)
     if logits_mode == "hidden":
         return x, new_cache, aux
     if logits_mode == "last":
@@ -156,22 +165,23 @@ def loss_fn(params, cfg: ModelConfig, batch: dict,
     def ce_chunk(h, t):
         return _ce_terms(params, cfg, h, t)
 
-    if n_chunks > 1:
-        Tm = n_chunks * chunk
-        hs = jnp.moveaxis(pred_h[:, :Tm].reshape(B, n_chunks, chunk, -1), 1, 0)
-        ts = jnp.moveaxis(targets[:, :Tm].reshape(B, n_chunks, chunk), 1, 0)
+    with jax.named_scope("loss"):
+        if n_chunks > 1:
+            Tm = n_chunks * chunk
+            hs = jnp.moveaxis(pred_h[:, :Tm].reshape(B, n_chunks, chunk, -1), 1, 0)
+            ts = jnp.moveaxis(targets[:, :Tm].reshape(B, n_chunks, chunk), 1, 0)
 
-        def body(acc, inp):
-            nll_s, z_s, cnt = ce_chunk(*inp)
-            return (acc[0] + nll_s, acc[1] + z_s, acc[2] + cnt), None
+            def body(acc, inp):
+                nll_s, z_s, cnt = ce_chunk(*inp)
+                return (acc[0] + nll_s, acc[1] + z_s, acc[2] + cnt), None
 
-        (nll_sum, z_sum, count), _ = jax.lax.scan(
-            body, (jnp.zeros(()), jnp.zeros(()), jnp.zeros(())), (hs, ts))
-        if rem:
-            n2, z2, c2 = ce_chunk(pred_h[:, Tm:], targets[:, Tm:])
-            nll_sum, z_sum, count = nll_sum + n2, z_sum + z2, count + c2
-    else:
-        nll_sum, z_sum, count = ce_chunk(pred_h, targets)
+            (nll_sum, z_sum, count), _ = jax.lax.scan(
+                body, (jnp.zeros(()), jnp.zeros(()), jnp.zeros(())), (hs, ts))
+            if rem:
+                n2, z2, c2 = ce_chunk(pred_h[:, Tm:], targets[:, Tm:])
+                nll_sum, z_sum, count = nll_sum + n2, z_sum + z2, count + c2
+        else:
+            nll_sum, z_sum, count = ce_chunk(pred_h, targets)
 
     nll = nll_sum / count
     zloss = z_sum / count

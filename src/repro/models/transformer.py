@@ -105,34 +105,42 @@ def apply_sublayer(p, cfg: ModelConfig, sub: SubLayer, x, positions,
                    cache=None, cache_index=None):
     """returns (x, new_cache, aux_loss)."""
     aux = jnp.zeros((), jnp.float32)
-    h = L.norm(cfg.norm, p["norm1"], x)
+    with jax.named_scope("norm"):
+        h = L.norm(cfg.norm, p["norm1"], x)
     if sub.mixer == "a":
-        out, new_kv = A.attention(p["attn"], cfg, h, positions,
-                                  kv_cache=cache, cache_index=cache_index)
+        with jax.named_scope("attention"):
+            out, new_kv = A.attention(p["attn"], cfg, h, positions,
+                                      kv_cache=cache, cache_index=cache_index)
         new_cache = new_kv
     elif sub.mixer == "m":
         state = (cache["conv"], cache["h"]) if cache is not None else None
-        out, (conv, hst) = S.mamba_mix(p["mamba"], cfg, h, state)
+        with jax.named_scope("ssm"):
+            out, (conv, hst) = S.mamba_mix(p["mamba"], cfg, h, state)
         new_cache = {"conv": conv, "h": hst} if cache is not None else None
     else:
         state = (cache["x_prev"], cache["S"]) if cache is not None else None
-        out, (xp, st) = S.rwkv6_mix(p["rwkv"], cfg, h, state)
+        with jax.named_scope("ssm"):
+            out, (xp, st) = S.rwkv6_mix(p["rwkv"], cfg, h, state)
         new_cache = ({"x_prev": xp, "S": st, "cm_prev": cache["cm_prev"]}
                      if cache is not None else None)
     x = x + autoshard.barrier(out)
 
-    h = L.norm(cfg.norm, p["norm2"], x)
+    with jax.named_scope("norm"):
+        h = L.norm(cfg.norm, p["norm2"], x)
     x = autoshard.hidden(x)
     if sub.mixer == "r":
-        out, cmp_ = S.rwkv6_channel_mix(
-            p["cmix"], cfg, h,
-            cache["cm_prev"] if cache is not None else None)
+        with jax.named_scope("ffn"):
+            out, cmp_ = S.rwkv6_channel_mix(
+                p["cmix"], cfg, h,
+                cache["cm_prev"] if cache is not None else None)
         if new_cache is not None:
             new_cache = dict(new_cache, cm_prev=cmp_)
     elif sub.is_moe:
-        out, aux = M.moe_ffn(p["moe"], cfg, h)
+        with jax.named_scope("moe"):
+            out, aux = M.moe_ffn(p["moe"], cfg, h)
     else:
-        out = L.ffn(p["ffn"], h, cfg.act, jnp.dtype(cfg.dtype))
+        with jax.named_scope("ffn"):
+            out = L.ffn(p["ffn"], h, cfg.act, jnp.dtype(cfg.dtype))
     return autoshard.hidden(x + autoshard.barrier(out)), new_cache, aux
 
 
@@ -211,13 +219,16 @@ def apply_stack(params, cfg: ModelConfig, x, positions, cache=None,
 
         body = _remat_wrap(cfg, period_body)
         pcaches = cache["periods"] if cache is not None else None
-        if pcaches is None:
-            (x, aux_total), _ = jax.lax.scan(
-                lambda carry, pp: (body(carry, (pp, None))[0], None),
-                (x, aux_total), params["periods"])
-        else:
-            (x, aux_total), ncaches = jax.lax.scan(
-                lambda carry, sc: body(carry, sc),
-                (x, aux_total), (params["periods"], pcaches))
-            new_cache["periods"] = ncaches
+        # The scan's own slicing of the stacked weights and caches, and
+        # its restacking of the new caches, fall under this scope.
+        with jax.named_scope("layer_scan"):
+            if pcaches is None:
+                (x, aux_total), _ = jax.lax.scan(
+                    lambda carry, pp: (body(carry, (pp, None))[0], None),
+                    (x, aux_total), params["periods"])
+            else:
+                (x, aux_total), ncaches = jax.lax.scan(
+                    lambda carry, sc: body(carry, sc),
+                    (x, aux_total), (params["periods"], pcaches))
+                new_cache["periods"] = ncaches
     return x, new_cache, aux_total

@@ -5,8 +5,15 @@ record lands in the active :class:`~repro.obs.record.TraceRecorder` (and
 exports into the same Perfetto trace as the cycle-level lanes) and its
 duration feeds a ``span.<name>.seconds`` histogram in the metrics registry.
 
-Every span also snapshots the ``repro.perf`` memo counters on entry/exit
-and tags itself with the hit/miss delta plus a derived provenance:
+Every span also enters ``jax.profiler.TraceAnnotation(name)``: inside a
+``jax.profiler`` trace it is a host event on the same clock as the device
+ops, so a device trace can say what the program was doing around an idle
+gap or a run of device work.  Outside a trace the annotation is the
+profiler's own inactive check.
+
+A span that records (inside a session) also snapshots the ``repro.perf``
+memo counters on entry/exit and tags itself with the hit/miss delta plus
+a derived provenance:
 
 * ``"hit"``   — the memo served everything (warm pricing),
 * ``"cold"``  — every lookup missed (fresh simulation),
@@ -19,8 +26,9 @@ That is the per-span half of the memo-parity story: a traced run can show
 
 from __future__ import annotations
 
+import functools
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 
 from repro.obs import metrics as _metrics
 from repro.obs import record as _record
@@ -35,6 +43,22 @@ def _memo_counts() -> tuple[int, int]:
     return hits, misses
 
 
+@functools.cache
+def _annotation():
+    """``jax.profiler.TraceAnnotation`` whose ``with`` yields ``None``,
+    made at the first span so that importing ``repro.obs`` imports no
+    JAX."""
+    from jax.profiler import TraceAnnotation
+
+    class Annotation(TraceAnnotation):
+        __slots__ = ()
+
+        def __enter__(self):
+            super().__enter__()
+
+    return Annotation
+
+
 def _provenance(hits: int, misses: int) -> str:
     if hits and misses:
         return "mixed"
@@ -45,31 +69,38 @@ def _provenance(hits: int, misses: int) -> str:
     return "none"
 
 
-@contextmanager
 def span(name: str, **attrs):
-    """Profile a scope.  Yields the (mutable) span record, or ``None`` when
-    observability is fully disabled — the no-op path costs two ContextVar
-    reads."""
+    """Profile a scope: ``with span(name, **attrs) as sp``, where ``sp`` is
+    the (mutable) span record, or ``None`` when no session records spans.
+    Without a session a span is the profiler annotation alone: two
+    ContextVar reads and the annotation's inactive check."""
+    if not _record._HOOKS_ENABLED:
+        return nullcontext()
     rec = _record.active_recorder()
     metrics_on = _metrics.enabled()
     if rec is None and not metrics_on:
-        yield None
-        return
-    h0, m0 = _memo_counts()
-    t0 = time.perf_counter()
-    sp = {"name": name, "attrs": dict(attrs),
-          "depth": rec.span_begin() if rec is not None else 1,
-          "start_s": (t0 - rec.created_s) if rec is not None else t0}
-    try:
-        yield sp
-    finally:
-        dur = time.perf_counter() - t0
-        h1, m1 = _memo_counts()
-        sp["dur_s"] = dur
-        sp["memo_hits"] = h1 - h0
-        sp["memo_misses"] = m1 - m0
-        sp["memo_provenance"] = _provenance(h1 - h0, m1 - m0)
-        if rec is not None:
-            rec.span_end(sp)
-        if metrics_on:
-            _metrics.REGISTRY.histogram(f"span.{name}.seconds").observe(dur)
+        return _annotation()(name)
+    return _recorded(name, attrs, rec, metrics_on)
+
+
+@contextmanager
+def _recorded(name: str, attrs: dict, rec, metrics_on: bool):
+    with _annotation()(name):
+        h0, m0 = _memo_counts()
+        t0 = time.perf_counter()
+        sp = {"name": name, "attrs": dict(attrs),
+              "depth": rec.span_begin() if rec is not None else 1,
+              "start_s": (t0 - rec.created_s) if rec is not None else t0}
+        try:
+            yield sp
+        finally:
+            dur = time.perf_counter() - t0
+            h1, m1 = _memo_counts()
+            sp["dur_s"] = dur
+            sp["memo_hits"] = h1 - h0
+            sp["memo_misses"] = m1 - m0
+            sp["memo_provenance"] = _provenance(h1 - h0, m1 - m0)
+            if rec is not None:
+                rec.span_end(sp)
+            if metrics_on:
+                _metrics.REGISTRY.histogram(f"span.{name}.seconds").observe(dur)

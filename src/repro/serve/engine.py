@@ -10,8 +10,6 @@ COPIFT machinery.
 
 from __future__ import annotations
 
-import functools
-import time
 import zlib
 from dataclasses import dataclass
 
@@ -24,7 +22,6 @@ from repro.configs.base import ModelConfig
 from repro.kernels import ops as kops
 from repro.models.model import forward
 from repro.models.transformer import init_stack_cache
-from repro.obs import metrics as _obs_metrics
 from repro.obs.spans import span as _obs_span
 
 
@@ -135,7 +132,6 @@ class ServeEngine:
             # Snitch-cluster deployment of the engine would pin.
             tuner = api.Tuner(api.Target.homogeneous(
                 power_cap_mw=power_cap_mw))
-            t0 = time.perf_counter()
             with _obs_span("serve.autotune", power_cap_mw=power_cap_mw):
                 self.operating_plan = {
                     name: tuner.operating_point(name, heterogeneous=True,
@@ -153,29 +149,6 @@ class ServeEngine:
                         name: sys_tuner.operating_point(
                             name, n_clusters=system.n_clusters)
                         for name in ("softmax", "prng")}
-            if _obs_metrics.enabled():
-                _obs_metrics.set_gauge("serve.autotune.wall_s",
-                                       time.perf_counter() - t0)
-                for name, res in self.operating_plan.items():
-                    c = res.best_cost
-                    _obs_metrics.set_gauge(
-                        f"serve.plan.{name}.cycles", c.cycles)
-                    _obs_metrics.set_gauge(
-                        f"serve.plan.{name}.energy_pj", c.energy_pj)
-                    _obs_metrics.set_gauge(
-                        f"serve.plan.{name}.power_mw", c.power_mw)
-                    _obs_metrics.set_gauge(
-                        f"serve.plan.{name}.time_ns", c.time_ns)
-                if self.system_plan is not None:
-                    for name, res in self.system_plan.items():
-                        c = res.best_cost
-                        _obs_metrics.set_gauge(
-                            f"serve.plan.system.{name}.n_clusters",
-                            res.n_clusters)
-                        _obs_metrics.set_gauge(
-                            f"serve.plan.system.{name}.power_mw", c.power_mw)
-                        _obs_metrics.set_gauge(
-                            f"serve.plan.system.{name}.time_ns", c.time_ns)
         self._prefill = jax.jit(make_prefill(cfg))
         self._step = jax.jit(make_serve_step(cfg))
 
@@ -220,19 +193,25 @@ class ServeEngine:
 
     def _sample(self, logits: jax.Array, step: int,
                 slot_seeds: list[int]) -> jax.Array:
-        if self.temperature <= 0.0:
-            return jnp.argmax(logits, axis=-1)
-        # Gumbel trick with xoshiro uniforms (the paper's PRNG), one
-        # counter stream per (engine, slot, step).
-        u = jnp.stack([kops.uniform(_mix32(s, step), logits.shape[-1:])
-                       for s in slot_seeds])
-        g = -jnp.log(-jnp.log(jnp.maximum(u, 1e-12)))
-        return jnp.argmax(logits / self.temperature + g, axis=-1)
+        with _obs_span("serve.sample"):
+            if self.temperature <= 0.0:
+                return jnp.argmax(logits, axis=-1)
+            # Gumbel trick with xoshiro uniforms (the paper's PRNG), one
+            # counter stream per (engine, slot, step).
+            u = jnp.stack([kops.uniform(_mix32(s, step), logits.shape[-1:])
+                           for s in slot_seeds])
+            g = -jnp.log(-jnp.log(jnp.maximum(u, 1e-12)))
+            return jnp.argmax(logits / self.temperature + g, axis=-1)
 
     def generate(self, prompts: np.ndarray, n_steps: int) -> GenerationResult:
         """prompts: (B, P) int32; decodes exactly ``n_steps`` tokens.
         ``n_steps=0`` returns the prompt unchanged (no prefill, no
-        sampled token)."""
+        sampled token).
+
+        Each host step runs in a ``repro.obs`` span (``serve.generate``
+        around ``serve.cache_init``, ``serve.prefill``, then per token
+        ``serve.sample`` and ``serve.decode_step``, and ``serve.collect``),
+        so a profiler trace shows what the host did beside the device."""
         prompts = np.asarray(prompts)
         B, plen = prompts.shape
         if B != self.batch:
@@ -247,17 +226,23 @@ class ServeEngine:
                 f"prompt length {plen} + n_steps={n_steps} = "
                 f"{plen + n_steps} exceeds max_len={self.max_len}; raise "
                 f"max_len or decode fewer steps.")
-        toks = jnp.asarray(prompts, jnp.int32)
-        if n_steps == 0:
-            return GenerationResult(np.asarray(toks), 0)
-        slot_seeds = self._slot_seeds(prompts)
-        cache = make_cache(self.cfg, B, self.max_len)
-        logits, cache = self._prefill(self.params, cache, toks)
-        out = [toks]
-        for i in range(n_steps):
-            tok = self._sample(logits, i, slot_seeds)[:, None]
-            out.append(tok)
-            if i + 1 < n_steps:
-                logits, cache = self._step(self.params, cache, tok,
-                                           jnp.int32(plen + i))
-        return GenerationResult(np.asarray(jnp.concatenate(out, 1)), n_steps)
+        with _obs_span("serve.generate"):
+            toks = jnp.asarray(prompts, jnp.int32)
+            if n_steps == 0:
+                return GenerationResult(np.asarray(toks), 0)
+            slot_seeds = self._slot_seeds(prompts)
+            with _obs_span("serve.cache_init"):
+                cache = make_cache(self.cfg, B, self.max_len)
+            with _obs_span("serve.prefill"):
+                logits, cache = self._prefill(self.params, cache, toks)
+            out = [toks]
+            for i in range(n_steps):
+                tok = self._sample(logits, i, slot_seeds)[:, None]
+                out.append(tok)
+                if i + 1 < n_steps:
+                    with _obs_span("serve.decode_step"):
+                        logits, cache = self._step(self.params, cache, tok,
+                                                   jnp.int32(plen + i))
+            with _obs_span("serve.collect"):
+                tokens = np.asarray(jnp.concatenate(out, 1))
+        return GenerationResult(tokens, n_steps)

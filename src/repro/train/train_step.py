@@ -75,8 +75,9 @@ def make_train_step(cfg: ModelConfig, opt_cfg: AdamWConfig,
             grads = jax.tree.map(
                 lambda g: quantize_dequantize(g.astype(jnp.float32))[0].astype(
                     g.dtype), grads)
-        params, opt, opt_metrics = adamw_update(
-            opt_cfg, state["params"], grads, state["opt"])
+        with jax.named_scope("optimizer"):
+            params, opt, opt_metrics = adamw_update(
+                opt_cfg, state["params"], grads, state["opt"])
         metrics = dict(metrics, loss=loss, **opt_metrics)
         return {"params": params, "opt": opt}, metrics
 

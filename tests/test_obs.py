@@ -300,6 +300,48 @@ class TestSpans:
         with obs.span("free") as handle:
             assert handle is None
 
+    def test_span_is_a_profiler_host_event(self, tmp_path):
+        """Inside a ``jax.profiler`` trace every span is a host event, with
+        or without a session; a session still records it."""
+        with obs.session() as sess:
+            events = _profiled_host_events(tmp_path, lambda: _nested_spans())
+        names = [n for _, _, n in events]
+        assert names.index("outer") < names.index("inner")
+        (os_, oe, _), (is_, ie, _) = (e for e in events if e[2] in ("outer", "inner"))
+        assert os_ <= is_ and ie <= oe
+        assert {s["name"] for s in sess.recorder.spans} == {"outer", "inner"}
+
+    def test_bypassed_span_reaches_no_sink(self, tmp_path):
+        from repro.obs import record as obs_record
+        with obs_record.hooks_bypassed():
+            events = _profiled_host_events(tmp_path, lambda: _nested_spans())
+        assert not {"outer", "inner"} & {n for _, _, n in events}
+
+
+def _nested_spans():
+    with obs.span("outer", label="x"):
+        with obs.span("inner"):
+            pass
+
+
+def _profiled_host_events(tmp_path, fn):
+    """(start_ns, end_ns, name) of the host events of a ``jax.profiler``
+    trace around ``fn()``, in order of start."""
+    import glob
+
+    import jax
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    return sorted((e.start_ns, e.start_ns + e.duration_ns, e.name)
+                  for plane in jax.profiler.ProfileData.from_file(path).planes
+                  if plane.name.startswith("/host:")
+                  for line in plane.lines for e in line.events)
+
 
 # ---------------------------------------------------------------------------
 # 5. Recorder mechanics
@@ -408,14 +450,45 @@ class TestServeEngine:
         finally:
             kops.set_tuned_defaults(False)
         m = sess.metrics()
-        assert m["serve.autotune.wall_s"]["value"] > 0
+        # The autotune's time is its span's histogram; its plan is read
+        # from the engine, not copied into gauges.
+        hist = m["span.serve.autotune.seconds"]
+        assert hist["type"] == "histogram" and hist["count"] == 1
+        assert hist["total"] > 0
+        assert not [k for k in m if k.startswith(("serve.plan.", "serve.autotune."))]
         for name in ("softmax", "prng"):
-            res = eng.operating_plan[name]
-            assert m[f"serve.plan.{name}.cycles"]["value"] == \
-                res.best_cost.cycles
-            assert m[f"serve.plan.{name}.power_mw"]["value"] == \
-                res.best_cost.power_mw
-        assert "span.serve.autotune.seconds" in m
+            cost = eng.operating_plan[name].best_cost
+            assert cost.cycles > 0 and cost.time_ns > 0
+            assert 0 < cost.power_mw <= 250.0
+        assert eng.system_plan is None
+
+    @pytest.mark.parametrize("temperature", [0.0, 0.8])
+    def test_generate_spans_each_host_step(self, tmp_path, temperature):
+        """One ``generate`` call marks its host steps in order, one span
+        per step (never per slot), all inside ``serve.generate``."""
+        import jax
+        import numpy as np
+
+        from repro.configs import load_config
+        from repro.models.model import init_params
+        from repro.serve.engine import ServeEngine
+
+        cfg = load_config("olmo-1b", "smoke")
+        eng = ServeEngine(cfg, init_params(cfg, jax.random.PRNGKey(0)),
+                          max_len=16, batch=2, temperature=temperature)
+        prompts = np.ones((2, 4), np.int32)
+        eng.generate(prompts, 3)
+        events = [e for e in _profiled_host_events(
+            tmp_path, lambda: eng.generate(prompts, 3))
+            if e[2].startswith("serve.")]
+        assert [n for _, _, n in events] == [
+            "serve.generate", "serve.cache_init", "serve.prefill",
+            "serve.sample", "serve.decode_step", "serve.sample",
+            "serve.decode_step", "serve.sample", "serve.collect"]
+        lo, hi, _ = events[0]
+        assert all(lo <= s and e <= hi for s, e, _ in events[1:])
+        steps = [(s, e) for s, e, _ in events[1:]]
+        assert all(e1 <= s2 for (_, e1), (s2, _) in zip(steps, steps[1:]))
 
 
 # ---------------------------------------------------------------------------
