@@ -181,10 +181,12 @@ def _chunked_attention(cfg: ModelConfig, q, k, v, q_offset, valid_limit=None):
 
 
 def attention(p, cfg: ModelConfig, x, positions, kv_cache=None,
-              cache_index=None):
-    """x: (B, T, D).  Training/prefill: kv_cache None.
-    Decode: kv_cache = dict(k=(B, S, Hkv, Dh), v=...), cache_index scalar —
-    writes the new token at ``cache_index`` and attends over the cache.
+              cache_index=None, layer=None):
+    """x: (B, T, D).  Training: kv_cache None.
+    Prefill/decode: kv_cache = dict(k=(B, S, Hkv, Dh), v=...), or those
+    stacked over the layer scan's periods with ``layer`` the period;
+    cache_index scalar — writes the T new positions at ``cache_index``
+    (``L.cache_write``), then attends over the layer's cache read back.
     Returns (out, new_kv_cache)."""
     dt = jnp.dtype(cfg.dtype)
     B, T, _ = x.shape
@@ -200,11 +202,13 @@ def attention(p, cfg: ModelConfig, x, positions, kv_cache=None,
     k = _rotate(cfg, k, positions)
 
     if kv_cache is not None:
-        k = jax.lax.dynamic_update_slice(
-            kv_cache["k"], k.astype(kv_cache["k"].dtype), (0, cache_index, 0, 0))
-        v = jax.lax.dynamic_update_slice(
-            kv_cache["v"], v.astype(kv_cache["v"].dtype), (0, cache_index, 0, 0))
-        new_cache = {"k": k, "v": v}
+        # Write first, then read the layer back: the read then sees only
+        # the written cache, which a donated or scan-carried cache keeps
+        # in place, and the compiler can fuse it into the einsums below.
+        new_cache = {"k": L.cache_write(kv_cache["k"], k, layer, cache_index),
+                     "v": L.cache_write(kv_cache["v"], v, layer, cache_index)}
+        k = L.cache_slot(new_cache["k"], layer)
+        v = L.cache_slot(new_cache["v"], layer)
         q_offset = cache_index
     else:
         new_cache = None

@@ -7,6 +7,11 @@ Conventions:
   to ``cfg.dtype`` (bf16) at use — mixed-precision training;
 * every init takes an explicit ``jax.random.PRNGKey``;
 * weight layouts are (d_in, d_out) so TP sharding specs read naturally.
+
+Cache leaves are one layer's state, (B, ...), or a stack of them over the
+layer scan's periods, (n_periods, B, ...); ``cache_slot`` and
+``cache_write`` take ``layer`` None for the first and the period index for
+the second.
 """
 
 from __future__ import annotations
@@ -159,3 +164,24 @@ def ffn(p, x, act: str, dtype):
     else:
         up = act_fn(act, up)
     return linear(p["down"], up, dtype)
+
+
+# ---------------------------------------------------------------------------
+# decode caches
+# ---------------------------------------------------------------------------
+
+def cache_slot(leaf, layer=None):
+    """One layer's state of a cache leaf: period ``layer`` of a stacked
+    leaf, or the leaf itself when ``layer`` is None."""
+    return leaf if layer is None else leaf[layer]
+
+
+def cache_write(leaf, update, layer=None, pos=0):
+    """``leaf`` with ``update`` written at ``pos`` along the layer state's
+    axis 1 (the sequence axis of a KV cache) and, for a stacked leaf, at
+    period ``layer``.  A dynamic-update-slice: in place when the leaf is
+    donated or carried through a scan, so only ``update`` is written."""
+    lead = () if layer is None else (layer,)
+    start = lead + (0, pos) + (0,) * (update.ndim - 2)
+    update = update.reshape((1,) * len(lead) + update.shape)
+    return jax.lax.dynamic_update_slice(leaf, update.astype(leaf.dtype), start)

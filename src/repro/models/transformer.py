@@ -9,7 +9,8 @@ ONE period body regardless of depth — compile times on the 512-device mesh
 stay flat in n_layers (the FREP/L0-I$ lesson applied at cluster scale).
 
 Caches (KV for attention, recurrent states for mamba/rwkv) are pytrees with
-a leading (n_periods, ...) axis consumed by the same scan.
+a leading (n_periods, ...) axis, carried through the same scan and updated
+in place.
 """
 
 from __future__ import annotations
@@ -19,6 +20,7 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.configs.base import ModelConfig
 from repro.models import attention as A
@@ -102,27 +104,34 @@ def init_sublayer_cache(cfg: ModelConfig, sub: SubLayer, batch: int,
 
 
 def apply_sublayer(p, cfg: ModelConfig, sub: SubLayer, x, positions,
-                   cache=None, cache_index=None):
-    """returns (x, new_cache, aux_loss)."""
+                   cache=None, cache_index=None, layer=None):
+    """returns (x, new_cache, aux_loss).  ``cache`` is the sub-layer's
+    decode state, stacked over the layer scan's periods with ``layer`` the
+    period (``layer`` None: one layer's state); the new cache is it with
+    this step's update written in (``L.cache_write``)."""
     aux = jnp.zeros((), jnp.float32)
     with jax.named_scope("norm"):
         h = L.norm(cfg.norm, p["norm1"], x)
+    # mamba/rwkv read their O(1) state and write all of it back
+    slot = (jax.tree.map(lambda a: L.cache_slot(a, layer), cache)
+            if cache is not None and sub.mixer != "a" else None)
+    new_cache = new_state = None
     if sub.mixer == "a":
         with jax.named_scope("attention"):
-            out, new_kv = A.attention(p["attn"], cfg, h, positions,
-                                      kv_cache=cache, cache_index=cache_index)
-        new_cache = new_kv
+            out, new_cache = A.attention(p["attn"], cfg, h, positions,
+                                         kv_cache=cache,
+                                         cache_index=cache_index, layer=layer)
     elif sub.mixer == "m":
-        state = (cache["conv"], cache["h"]) if cache is not None else None
+        state = (slot["conv"], slot["h"]) if slot is not None else None
         with jax.named_scope("ssm"):
             out, (conv, hst) = S.mamba_mix(p["mamba"], cfg, h, state)
-        new_cache = {"conv": conv, "h": hst} if cache is not None else None
+        new_state = {"conv": conv, "h": hst} if slot is not None else None
     else:
-        state = (cache["x_prev"], cache["S"]) if cache is not None else None
+        state = (slot["x_prev"], slot["S"]) if slot is not None else None
         with jax.named_scope("ssm"):
             out, (xp, st) = S.rwkv6_mix(p["rwkv"], cfg, h, state)
-        new_cache = ({"x_prev": xp, "S": st, "cm_prev": cache["cm_prev"]}
-                     if cache is not None else None)
+        new_state = ({"x_prev": xp, "S": st, "cm_prev": slot["cm_prev"]}
+                     if slot is not None else None)
     x = x + autoshard.barrier(out)
 
     with jax.named_scope("norm"):
@@ -132,15 +141,18 @@ def apply_sublayer(p, cfg: ModelConfig, sub: SubLayer, x, positions,
         with jax.named_scope("ffn"):
             out, cmp_ = S.rwkv6_channel_mix(
                 p["cmix"], cfg, h,
-                cache["cm_prev"] if cache is not None else None)
-        if new_cache is not None:
-            new_cache = dict(new_cache, cm_prev=cmp_)
+                slot["cm_prev"] if slot is not None else None)
+        if new_state is not None:
+            new_state = dict(new_state, cm_prev=cmp_)
     elif sub.is_moe:
         with jax.named_scope("moe"):
             out, aux = M.moe_ffn(p["moe"], cfg, h)
     else:
         with jax.named_scope("ffn"):
             out = L.ffn(p["ffn"], h, cfg.act, jnp.dtype(cfg.dtype))
+    if new_state is not None:
+        new_cache = jax.tree.map(lambda a, u: L.cache_write(a, u, layer),
+                                 cache, new_state)
     return autoshard.hidden(x + autoshard.barrier(out)), new_cache, aux
 
 
@@ -188,6 +200,16 @@ def _remat_wrap(cfg: ModelConfig, fn):
     return fn
 
 
+def _row_major(leaf):
+    """``leaf`` held in the default (row-major) layout, the one a cache
+    has where the program receives and returns it.  Left free, the
+    compiler gives a carried KV cache the layout its decode einsums
+    prefer, and so relays out the whole cache on the program's entry and
+    exit; in this layout the einsums read each layer's slice as it is."""
+    return with_layout_constraint(
+        leaf, Layout(major_to_minor=tuple(range(leaf.ndim))))
+
+
 def apply_stack(params, cfg: ModelConfig, x, positions, cache=None,
                 cache_index=None):
     """returns (x, new_cache, total_aux)."""
@@ -203,32 +225,40 @@ def apply_stack(params, cfg: ModelConfig, x, positions, cache=None,
         if cache is not None:
             new_cache["prefix"].append(nc)
 
-    if n_periods:
-        def period_body(carry, scanned):
+    if n_periods and cache is None:
+        def period_body(carry, pparams):
             x, aux_acc = carry
-            pparams, pcache = scanned
-            ncache = {} if pcache is not None else None
             for i, sub in enumerate(period):
-                c = pcache[f"sub{i}"] if pcache is not None else None
-                x, nc, aux = apply_sublayer(pparams[f"sub{i}"], cfg, sub, x,
-                                            positions, c, cache_index)
+                x, _, aux = apply_sublayer(pparams[f"sub{i}"], cfg, sub, x,
+                                           positions)
                 aux_acc = aux_acc + aux
-                if ncache is not None:
-                    ncache[f"sub{i}"] = nc
-            return (x, aux_acc), ncache
+            return (x, aux_acc), None
 
-        body = _remat_wrap(cfg, period_body)
-        pcaches = cache["periods"] if cache is not None else None
-        # The scan's own slicing of the stacked weights and caches, and
-        # its restacking of the new caches, fall under this scope.
+        # The scan's own slicing of the stacked weights falls under this
+        # scope.
         with jax.named_scope("layer_scan"):
-            if pcaches is None:
-                (x, aux_total), _ = jax.lax.scan(
-                    lambda carry, pp: (body(carry, (pp, None))[0], None),
-                    (x, aux_total), params["periods"])
-            else:
-                (x, aux_total), ncaches = jax.lax.scan(
-                    lambda carry, sc: body(carry, sc),
-                    (x, aux_total), (params["periods"], pcaches))
-                new_cache["periods"] = ncaches
+            (x, aux_total), _ = jax.lax.scan(
+                _remat_wrap(cfg, period_body), (x, aux_total),
+                params["periods"])
+    elif n_periods:
+        # The stacked caches ride in the carry, not in ``xs``: each
+        # sub-layer writes its update into them at its period, so a
+        # donated cache is updated in place, never sliced out and
+        # restacked.
+        def cached_body(carry, scanned):
+            x, aux_acc, pcaches = carry
+            pparams, layer = scanned
+            new = {}
+            for i, sub in enumerate(period):
+                key = f"sub{i}"
+                x, new[key], aux = apply_sublayer(
+                    pparams[key], cfg, sub, x, positions, pcaches[key],
+                    cache_index, layer)
+                aux_acc = aux_acc + aux
+            return (x, aux_acc, jax.tree.map(_row_major, new)), None
+
+        with jax.named_scope("layer_scan"):
+            (x, aux_total, new_cache["periods"]), _ = jax.lax.scan(
+                cached_body, (x, aux_total, cache["periods"]),
+                (params["periods"], jnp.arange(n_periods)))
     return x, new_cache, aux_total

@@ -17,7 +17,9 @@ Metric names are dotted strings (see the README glossary):
 * ``perf.memo.*`` — per-table entries/hits/misses/hit_rate gauges,
   snapshotted from ``perf.memo.stats()`` when a session closes.
 * ``tune.*`` — cost-oracle batch throughput and search-rung progress.
-* ``serve.*`` — engine autotune wall-time and chosen operating plans.
+* ``serve.*`` — the engine's ``serve.cache.donated`` (whether decode
+  steps update the KV cache in place) and the serving simulator's
+  ``serve.sim.*``.
 * ``span.<name>.seconds`` — wall-time histograms from ``obs.spans``.
 
 Like ``record``, this module imports nothing from ``repro``.

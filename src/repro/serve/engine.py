@@ -22,6 +22,7 @@ from repro.configs.base import ModelConfig
 from repro.kernels import ops as kops
 from repro.models.model import forward
 from repro.models.transformer import init_stack_cache
+from repro.obs import metrics as _obs_metrics
 from repro.obs.spans import span as _obs_span
 
 
@@ -149,8 +150,9 @@ class ServeEngine:
                         name: sys_tuner.operating_point(
                             name, n_clusters=system.n_clusters)
                         for name in ("softmax", "prng")}
-        self._prefill = jax.jit(make_prefill(cfg))
-        self._step = jax.jit(make_serve_step(cfg))
+        # Both programs take the cache donated and update it in place.
+        self._prefill = jax.jit(make_prefill(cfg), donate_argnums=1)
+        self._step = jax.jit(make_serve_step(cfg), donate_argnums=1)
 
     # -- lifecycle ----------------------------------------------------------
 
@@ -241,8 +243,14 @@ class ServeEngine:
                 out.append(tok)
                 if i + 1 < n_steps:
                     with _obs_span("serve.decode_step"):
+                        given = cache
                         logits, cache = self._step(self.params, cache, tok,
                                                    jnp.int32(plen + i))
+                    if i == 0:
+                        # 0 where a backend or caller keeps the cache
+                        # undonated, so every step copies it
+                        _obs_metrics.set_gauge("serve.cache.donated", int(all(
+                            a.is_deleted() for a in jax.tree.leaves(given))))
             with _obs_span("serve.collect"):
                 tokens = np.asarray(jnp.concatenate(out, 1))
         return GenerationResult(tokens, n_steps)

@@ -11,7 +11,8 @@ import jax.numpy as jnp
 from repro.configs import ARCHS, applicable_shapes, load_config
 from repro.models.model import forward, init_params, loss_fn
 from repro.models.transformer import layer_plan
-from repro.serve.engine import make_cache, make_prefill, make_serve_step
+from repro.serve.engine import (ServeEngine, make_cache, make_prefill,
+                                make_serve_step)
 from repro.train.optimizer import AdamWConfig
 from repro.train.train_step import init_train_state, make_train_step
 
@@ -103,6 +104,56 @@ class TestDecode:
                 np.asarray(logits_t), np.asarray(full[:, t]),
                 rtol=2e-2, atol=2e-2,
                 err_msg=f"{name} decode diverges at t={t}")
+
+
+    def test_donated_in_place_steps_match_undonated(self, arch_setup):
+        """A prefill and 3 steps through the engine's donated programs,
+        which write each call's update into the cache in place, give the
+        logits and caches of undonated jits bit for bit; each call leaves
+        every attention cache slot but the positions it writes as it was
+        (the slots start as junk, which the masks hide)."""
+        name, cfg, params = arch_setup
+        if cfg.is_encoder_only:
+            pytest.skip("encoder-only: no decode step")
+        B, plen, S = 2, 8, 16
+        tokens = jax.random.randint(KEY, (B, plen + 3), 0, cfg.vocab_size)
+        eng = ServeEngine(cfg, params, max_len=S, batch=B)
+        prefill = jax.jit(make_prefill(cfg))
+        step = jax.jit(make_serve_step(cfg))
+        junk = jax.tree_util.tree_map_with_path(
+            lambda path, a: (jax.random.normal(KEY, a.shape, a.dtype)
+                             if path[-1].key in ("k", "v") else a),
+            make_cache(cfg, B, S))
+
+        def same(a, b):
+            for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b),
+                            strict=True):
+                np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+        def call(program, ref_program, ref_cache, cache, toks, *index):
+            # a device copy: a host view of a buffer keeps it undonated
+            before = jax.tree.map(jnp.copy, cache)
+            ref = ref_program(params, ref_cache, toks, *index)
+            got = program(params, cache, toks, *index)
+            same(got, ref)
+            assert all(a.is_deleted() for a in jax.tree.leaves(cache))
+            start = int(index[0]) if index else 0
+            written = np.arange(start, start + toks.shape[1])
+            kv = [(old, new) for (path, old), new in zip(
+                jax.tree.leaves_with_path(before), jax.tree.leaves(got[1]),
+                strict=True) if path[-1].key in ("k", "v")]
+            assert kv or cfg.ssm is not None, name
+            for old, new in kv:       # (..., B, S, Hkv, Dh): S is axis -3
+                np.testing.assert_array_equal(
+                    np.delete(np.asarray(old), written, axis=-3),
+                    np.delete(np.asarray(new), written, axis=-3))
+            return ref[1], got[1]
+
+        ref_cache, cache = call(eng._prefill, prefill, junk,
+                                jax.tree.map(jnp.copy, junk), tokens[:, :plen])
+        for t in range(plen, plen + 3):
+            ref_cache, cache = call(eng._step, step, ref_cache, cache,
+                                    tokens[:, t:t + 1], jnp.int32(t))
 
 
 class TestLayerPlan:

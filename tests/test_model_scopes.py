@@ -64,6 +64,29 @@ def test_dense_programs_carry_scopes(dense, program, want):
         assert not {"loss", "optimizer"} & got
 
 
+@pytest.mark.parametrize("program", ["serve_step", "prefill"])
+def test_engine_programs_keep_scan_and_attention_scopes(dense, program):
+    """The engine's programs, with the cache donated and carried through
+    the layer scan, keep ``layer_scan`` and ``attention`` in the op_name
+    metadata that a trace carries, the in-place cache writes under
+    ``attention``."""
+    from repro.serve.engine import ServeEngine
+    cfg, params = dense
+    eng = ServeEngine(cfg, params, max_len=32, batch=2)
+    cache = jax.eval_shape(lambda: make_cache(cfg, 2, 32))
+    if program == "serve_step":
+        tok = jax.ShapeDtypeStruct((2, 1), jnp.int32)
+        lowered = eng._step.lower(params, cache, tok, jnp.int32(5))
+    else:
+        toks = jax.ShapeDtypeStruct((2, 8), jnp.int32)
+        lowered = eng._prefill.lower(params, cache, toks)
+    text = lowered.compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    assert any("/layer_scan/" in n and "/attention/" in n for n in names)
+    assert any(re.search(r"/layer_scan/.*/attention/dynamic_update_slice", n)
+               for n in names)
+
+
 def test_backward_carries_forward_scopes(dense):
     """In the compiled train step, the op_name metadata that a trace
     carries puts the backward's ops under the forward's scopes."""

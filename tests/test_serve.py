@@ -5,10 +5,13 @@ latency-constrained objective, and the discrete-event serving simulator
 policies, and the benchmark's acceptance inequality)."""
 
 import math
+import re
+import warnings
 
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops as kops
@@ -16,7 +19,7 @@ from repro.serve import (POLICIES, ModelPredictivePolicy, PolicyContext,
                          ReactivePolicy, Request, ServicePricer, SimReport,
                          SloSpec, SlotPlan, StaticPolicy, Trace, make_trace,
                          plan_for_rate, simulate)
-from repro.serve.engine import ServeEngine, _mix32
+from repro.serve.engine import ServeEngine, _mix32, make_cache
 
 
 def _engine(**kw):
@@ -53,6 +56,44 @@ class TestEngineZeroSteps:
             eng.generate(np.zeros((2, 4), np.int32), -1)
         with pytest.raises(ValueError, match=r"max_len=16"):
             eng.generate(np.zeros((2, 10), np.int32), 7)
+
+
+class TestEngineDonation:
+    def test_generate_donates_the_cache_in_place(self):
+        """``generate`` donates the cache to the prefill and every step:
+        the gauge reads 1, no donation falls back, and both compiled
+        programs alias their cache input to their cache output under the
+        names the device-trace readers key on."""
+        from repro import obs
+        from repro.configs import load_config
+        from repro.models.model import init_params
+
+        cfg = load_config("olmo-1b", "smoke")
+        params = init_params(cfg, jax.random.PRNGKey(0))
+        eng = ServeEngine(cfg, params, max_len=16, batch=2)
+        with warnings.catch_warnings(record=True) as caught:
+            warnings.simplefilter("always")
+            with obs.session(trace=False, metrics=True) as sess:
+                eng.generate(np.ones((2, 4), np.int32), 3)
+        assert not [w for w in caught if "donated" in str(w.message)]
+        assert sess.metrics()["serve.cache.donated"]["value"] == 1
+
+        cache = jax.eval_shape(lambda: make_cache(cfg, 2, 16))
+        tok = jax.ShapeDtypeStruct((2, 1), jnp.int32)
+        programs = {
+            "jit_serve_step": eng._step.lower(params, cache, tok, jnp.int32(4)),
+            "jit_prefill": eng._prefill.lower(
+                params, cache, jax.ShapeDtypeStruct((2, 4), jnp.int32))}
+        n_params, n_cache = (len(jax.tree.leaves(t)) for t in (params, cache))
+        # outputs (logits, *cache); inputs (*params, *cache, ...)
+        want = {(1 + i, n_params + i) for i in range(n_cache)}
+        for name, lowered in programs.items():
+            text = lowered.compile().as_text()
+            assert text.startswith(f"HloModule {name},")
+            aliases = re.search(r"input_output_alias=\{(.*?) \}, ", text)
+            got = {(int(o), int(i)) for o, i in re.findall(
+                r"\{(\d+)\}: \((\d+), \{\}", aliases.group(1))}
+            assert got == want, (name, got)
 
 
 class TestEngineTunedDefaultScope:

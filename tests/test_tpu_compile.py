@@ -9,6 +9,8 @@ only one process at a time may load the TPU library, and every test worker
 imports this file.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -108,3 +110,53 @@ def test_softmax_at_vmem_limit_is_accepted():
                     jnp.float32)
     y = ops.softmax(x, impl="pallas")
     np.testing.assert_allclose(np.asarray(y).sum(-1), 1.0, rtol=1e-5)
+
+
+def _top_level_ops(text: str, dims: str) -> list[tuple[str, str]]:
+    """(instruction, opcode) of every op outside fusion bodies whose
+    result has shape ``[dims]``."""
+    fused = set(re.findall(r"calls=%?([\w.\-]+)", text))
+    ops, body = [], None
+    for line in text.splitlines():
+        if not line.startswith(" ") and line.endswith("{"):
+            body = line.split()[1 if line.startswith("ENTRY") else 0].lstrip("%")
+            continue
+        m = re.match(rf"\s*(?:ROOT )?%?([\w.\-]+) = \w+\[{dims}\]\S* "
+                     r"([\w\-]+)\(", line)
+        if m and body not in fused:
+            ops.append(m.groups())
+    return ops
+
+
+def test_serve_step_updates_cache_in_place(one_chip, monkeypatch):
+    """The engine's decode step, compiled for a v5e at OLMo-1B's widths
+    (2 of 16 layers, batch 8, 2048 slots) with the Pallas softmax, moves
+    no whole layer or stack of the KV cache: the only cache-sized ops are
+    the two in-place writes of the new token's K and V.  Left to choose
+    the carried cache's layout, the compiler would relay out each stack on
+    entry and exit."""
+    from repro.configs import load_config
+    from repro.models.model import init_params
+    from repro.serve.engine import ServeEngine, make_cache
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    cfg = load_config("olmo-1b").replace(
+        n_layers=2, layer_types="aa", dtype="bfloat16", softmax_impl="pallas")
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    cache = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: make_cache(cfg, 8, 2048)))
+    tok = jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=one_chip)
+    index = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    eng = ServeEngine(cfg, params, max_len=2048, batch=8)
+    text = eng._step.lower(params, cache, tok, index).compile().as_text()
+    assert "tpu_custom_call" in text
+    layer = "8,2048,16,128"
+    assert not _top_level_ops(text, layer)
+    stack = [op for op in _top_level_ops(text, "2," + layer)
+             if op[1] not in ("parameter", "get-tuple-element", "while")]
+    assert len(stack) == 2 and all(
+        op == "fusion" and "dynamic-update-slice" in name
+        for name, op in stack), stack
