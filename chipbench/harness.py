@@ -8,7 +8,8 @@ traffic's ``trace_seconds``), reads the device's peak memory, frees the
 program's state, and lets the driver compare what the window produced
 with the plain reference.  The end-to-end metrics are computed here from
 the window's host-clock records; the per-layer metrics by the readers in
-``metrics/``.
+``metrics/``, from the trace with the program's spans and model scopes
+(``scopes.ScopedTrace``).
 """
 
 from __future__ import annotations
@@ -291,9 +292,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, t0: float,
               "count": len(devices), "memory_peak_bytes": int(peak)}
     metrics, breakdown = {}, None
     if trace:
-        from chipbench import trace as tr
+        from chipbench import scopes
 
-        reduced = tr.Trace(tr.load(log_dir))
+        t_read = time.perf_counter()
+        reduced = scopes.ScopedTrace(scopes.load(log_dir))
         shutil.rmtree(log_dir, ignore_errors=True)
         view = View(reduced, window, cell.config, cell.traffic,
                     _peaks(dev.device_kind), len(devices))
@@ -304,6 +306,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, t0: float,
         device["busy_s"] = reduced.busy_s()
         device["window_s"] = reduced.window_s()
         breakdown = {"device_ops": reduced.top_ops(), "idle_gaps": reduced.idle_gaps()}
+        print(f"trace read and reduced in {time.perf_counter() - t_read:.3f} s",
+              file=sys.stderr)
     else:
         for m in cell.end_to_end:
             value = setup_s if m["name"] == "setup_s" else END_TO_END[m["name"]](window)

@@ -23,6 +23,7 @@ reduces them and takes five-field events too (no op then has a scope).
 from __future__ import annotations
 
 import bisect
+import functools
 import glob
 import re
 from collections import defaultdict
@@ -39,12 +40,14 @@ _WRAPPED = re.compile(r"^[\w.-]*\((.*)\)$")
 _PROGRAM_ID = re.compile(r"\((\d+)\)$")
 
 
+@functools.cache
 def scope_path(op_name: str) -> tuple:
     """The model scopes in an instruction's ``op_name``, outermost first.
     Transformation wrappers are stripped, so a backward op reads as its
     forward: ``jit(train_step)/transpose(jvp(layer_scan))/while/body/
     closed_call/attention/dot_general`` -> ``("layer_scan", "attention")``.
-    Where XLA merged several names (``a/mul;b/add``) the first counts."""
+    Where XLA merged several names (``a/mul;b/add``) the first counts.
+    Cached: a trace repeats each instruction's ``op_name`` once a step."""
     out = []
     for part in op_name.split(";")[0].split("/"):
         while (m := _WRAPPED.match(part)):
@@ -187,10 +190,14 @@ def load(log_dir: str) -> list:
 def _parents(ops) -> list:
     """For each (start, duration) op of one device, the index of the
     innermost other op whose interval holds it (a ``while`` around its
-    body's ops), or -1."""
+    body's ops), or -1.  An op of no duration (the runtime's markers,
+    ``custom-call``s of 0 ns) neither holds nor is held: one that starts
+    with a fusion would otherwise make the fusion a container."""
     order = sorted(range(len(ops)), key=lambda i: (ops[i][0], -ops[i][1]))
     parent, stack = [-1] * len(ops), []
     for i in order:
+        if ops[i][1] <= 0:
+            continue
         s, e = ops[i][0], ops[i][0] + ops[i][1]
         while stack and ops[stack[-1]][0] + ops[stack[-1]][1] <= s:
             stack.pop()
@@ -212,6 +219,7 @@ class ScopedTrace(tr.Trace):
         for e in events:
             if e[0] == "op":
                 self.op_names[e[1]].append(e[5] if len(e) > 5 else "")
+        self._scoped = {}                   # module -> scope_seconds(module)
 
     def program_spans(self, name: str | None = None) -> list:
         """(start, end, name) of the program's spans, in order of start;
@@ -220,21 +228,35 @@ class ScopedTrace(tr.Trace):
                       if h[2].startswith(PROGRAM_SPANS) and name in (None, h[2]))
 
     def scope_seconds(self, module: str) -> tuple:
-        """(executions, {innermost scope: device seconds}) of program
-        ``module`` inside the window, over its leaf ops, summed over the
-        devices.  An op with no model scope of its own (a copy the
-        compiler put in) takes that of the op it runs inside (the layer
-        scan's ``while``); ops under no scope count under ``""``."""
+        """(executions, {innermost scope: device seconds}) of the
+        executions of program ``module`` that start inside the window, over
+        all their leaf ops (one that runs past the window's end included,
+        one that started before it left out, as ``module_runs`` counts
+        them), summed over the devices.  An op with no model scope of its
+        own (a copy the compiler put in) takes that of the op it runs
+        inside (the layer scan's ``while``); ops under no scope count
+        under ``""``, ops of no duration nowhere.  Reduced once per
+        program."""
+        if module not in self._scoped:
+            self._scoped[module] = self._scope_seconds(module)
+        runs, seconds = self._scoped[module]
+        return runs, dict(seconds)
+
+    def _scope_seconds(self, module: str) -> tuple:
         runs, _ = self.module_runs(module)
         out = defaultdict(float)
         for d in self.devices:
+            mods = self.modules.get(d, [])
+            starts = [m[0] for m in mods]
+            counted = [self.lo <= s < self.hi and tr.base(name) == module
+                       for s, _, name in mods]
             ops = self.ops[d]
             parent = _parents([(s, dur) for s, dur, _ in ops])
             outer = set(parent) - {-1}
             for i, (s, dur, _) in enumerate(ops):
-                if i in outer or not (self.lo <= s < self.hi):
-                    continue
-                if self._module_at(d, s) != module:
+                k = bisect.bisect_right(starts, s) - 1
+                if i in outer or dur <= 0 or k < 0 or not counted[k] \
+                        or s > mods[k][0] + mods[k][1]:
                     continue
                 j, path = i, ()
                 while j >= 0 and not (path := scope_path(self.op_names[d][j])):

@@ -11,8 +11,10 @@ the calibration and the tests need in place (``constant_uniforms``).
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import importlib
 import sys
+import typing
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -32,28 +34,87 @@ from repro.train.train_step import init_train_state  # noqa: E402
 
 _NORMS = {"nonparametric_layernorm": "nonparam_ln", "rmsnorm": "rmsnorm"}
 
+#: ``ModelConfig`` sizes and precisions, set from the file's keys.
+_SIZES = {"n_layers": "num_hidden_layers", "d_model": "hidden_size",
+          "n_heads": "num_attention_heads", "n_kv_heads": "num_key_value_heads",
+          "d_head": "head_dim", "d_ff": "intermediate_size", "vocab_size": "vocab_size",
+          "max_seq_len": "max_position_embeddings", "dtype": "compute_dtype",
+          "param_dtype": "param_dtype"}
+#: ``ModelConfig`` fields stated by keys of the file (``norm`` in the
+#: reference's names, ``hidden_act`` in the program's).
+_KEYS = {"norm": ("norm", _NORMS.get), "tie_embeddings": ("tie_word_embeddings", None),
+         "act": ("hidden_act", None), "rope_theta": ("rope_theta", None)}
+
 
 def adapter(c: dict):
     return importlib.import_module(f"chipbench.adapters.{c['reference']}")
 
 
+def architecture_fields(cls) -> list:
+    """The fields of the config class ``cls`` that say what the model
+    computes: all but its name and family, the sizes set from the file's
+    keys, and the "execution knobs" block of ``ModelConfig``, ``dtype``
+    through ``vocab_parallel_ce`` (how the program runs the model).  A
+    field added anywhere else counts as architecture."""
+    names = [f.name for f in dataclasses.fields(cls)]
+    knobs = names[names.index("dtype"):names.index("vocab_parallel_ce") + 1]
+    return [f for f in names if f not in knobs and f not in _SIZES
+            and f not in ("name", "family")]
+
+
+def _stated(c: dict) -> dict:
+    """The config fields that the file's own keys state."""
+    return {f: (to(c[k]) if to else c[k]) for f, (k, to) in _KEYS.items() if k in c}
+
+
+def _program(c: dict, cls) -> dict:
+    """The file's ``program`` object as field values of the config class
+    ``cls``: an object becomes the dataclass its field holds
+    (``MoEConfig``, ``SSMConfig``), a list a tuple.  Raises on a name
+    that the file's own keys already set, and on any other name that is
+    not an architecture field of ``cls`` (a knob such as
+    ``softmax_impl``, the name, or no field at all)."""
+    hints = typing.get_type_hints(cls)
+    arch = architecture_fields(cls)
+    out = {}
+    for name, value in c.get("program", {}).items():
+        if name in _SIZES or name in _stated(c):
+            raise ValueError(f"program field {name!r} is set by the file's own keys")
+        if name not in arch:
+            raise ValueError(f"program field {name!r} is not an architecture field "
+                             f"of {cls.__name__}")
+        if isinstance(value, dict):
+            kinds = [t for t in typing.get_args(hints[name]) or (hints[name],)
+                     if dataclasses.is_dataclass(t)]
+            if not kinds:
+                raise ValueError(f"program field {name!r} takes no object")
+            try:
+                value = kinds[0](**value)
+            except TypeError as e:
+                raise ValueError(f"program field {name!r}: {e}") from None
+        elif isinstance(value, list):
+            value = tuple(value)
+        out[name] = value
+    return out
+
+
 def program_config(c: dict):
-    """The registry's architecture ``c['arch']`` at the sizes and
-    precisions the file states.  Raises where the architecture's fixed
-    features (norm, tied head, activation, rotary base) differ from the
-    file."""
+    """The registry's architecture ``c['arch']``, cut to the file's depth
+    (``with_depth``, which keeps the registry's layer pattern), at the
+    sizes and precisions the file states.  Raises where an architecture
+    field of the registry's config differs from the file: from what the
+    file's keys (norm, tied head, activation, rotary base) or its
+    optional ``program`` object (architecture fields by name) state, and,
+    for a field the file does not state, from the field's default (a
+    dense all-attention model)."""
     base = load_config(c["arch"])
-    cfg = base.replace(
-        dtype=c["compute_dtype"], param_dtype=c["param_dtype"],
-        n_layers=c["num_hidden_layers"], layer_types="a" * c["num_hidden_layers"],
-        d_model=c["hidden_size"], n_heads=c["num_attention_heads"],
-        n_kv_heads=c["num_key_value_heads"], d_head=c["head_dim"],
-        d_ff=c["intermediate_size"], vocab_size=c["vocab_size"],
-        max_seq_len=c["max_position_embeddings"])
-    want = {"norm": _NORMS.get(c["norm"]), "tie_embeddings": c["tie_word_embeddings"],
-            "act": c["hidden_act"], "rope": "rope", "rope_theta": c["rope_theta"],
-            "qk_norm": False, "sliding_window": 0, "moe": None, "ssm": None}
-    wrong = {k: (getattr(cfg, k), v) for k, v in want.items() if getattr(cfg, k) != v}
+    cls = type(base)
+    sizes = {f: c[k] for f, k in _SIZES.items()}
+    program = _program(c, cls)
+    cfg = base.with_depth(sizes["n_layers"]).replace(**sizes)
+    want = cls(name=base.name, family=base.family, **sizes).replace(**_stated(c), **program)
+    wrong = {f: (getattr(cfg, f), getattr(want, f)) for f in architecture_fields(cls)
+             if getattr(cfg, f) != getattr(want, f)}
     if wrong:
         raise ValueError(f"{c['arch']} departs from its configuration file "
                          f"(program, file): {wrong}")

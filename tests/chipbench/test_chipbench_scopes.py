@@ -128,6 +128,37 @@ def test_scope_seconds():
                                   "optimizer": 50e-9})
 
 
+def _train_step_at(t0):
+    """TRAIN's step, its program and ops, moved to start at ``t0``."""
+    return [e[:3] + [e[3] + t0] + e[4:] for e in TRAIN if e[0] != "host"]
+
+
+def test_scope_seconds_counts_the_steps_that_start_in_the_window():
+    """A step that began before the window is left out whole and one that
+    the window's end cuts is counted whole, as ``module_runs`` counts them:
+    the per-step reading is that of one whole step."""
+    events = [host("window", 300, 1000), host("train_step", 300, 1000)]
+    events += _train_step_at(0) + _train_step_at(400) + _train_step_at(800)
+    t = scopes.ScopedTrace(events)
+    runs, secs = t.scope_seconds("jit_train_step")
+    assert runs == 2
+    assert secs == pytest.approx({"weight_cast": 40e-9, "attention": 320e-9, "ffn": 340e-9,
+                                  "optimizer": 100e-9})
+    assert read("attention_ms_per_step.train", view(t)) == pytest.approx(160e-6)
+    secs["attention"] = 0.0             # the reduction is kept, not the caller's copy
+    assert t.scope_seconds("jit_train_step")[1]["attention"] == pytest.approx(320e-9)
+
+
+def test_an_op_of_no_duration_makes_no_container():
+    """The runtime's 0 ns custom-calls that start with a fusion leave the
+    fusion a leaf: the reduction is that of the trace without them."""
+    marked = SERVE + [op("custom-call.1", 60, 60), op("custom-call.2", 170, 170),
+                      op("custom-call.3", 440, 440)]
+    for module in ("jit_prefill", "jit_serve_step"):
+        assert scopes.ScopedTrace(marked).scope_seconds(module) == \
+            pytest.approx(scopes.ScopedTrace(SERVE).scope_seconds(module))
+
+
 def test_program_spans_and_idle():
     t = scopes.ScopedTrace(SERVE)
     assert [n for _, _, n in t.program_spans()] == [
@@ -263,12 +294,54 @@ def test_recorded_decode_request():
     for module in ("jit_prefill", "jit_serve_step"):
         _, secs = t.scope_seconds(module)
         unscoped[module] = secs[""] / sum(secs.values())
-    assert unscoped == pytest.approx({"jit_prefill": 0.00991316573213012,
-                                      "jit_serve_step": 0.05276024166643063})
+    assert unscoped == pytest.approx({"jit_prefill": 0.009826528012728793,
+                                      "jit_serve_step": 0.05276025350827976})
     assert {name for name, _ in t.idle_gaps()} <= {"serve.cache_init", "serve.decode_step",
                                                    "serve.sample"}
     assert read("device_idle_share.sample", v) == pytest.approx(10.193757072326488)
     assert read("decode_step_weight_cast_ms", v) == pytest.approx(8.843599142857142)
     assert read("decode_step_layer_scan_ms", v) == pytest.approx(17.712916428571436)
-    assert read("attention_ms_per_prefill", v) == pytest.approx(112.12088599999994)
+    assert read("attention_ms_per_prefill", v) == pytest.approx(113.74669699999995)
     assert read("attention_ms_per_step.train", v) is None
+
+
+# -- the harness reads the trace with scopes: every reader as before -----------
+
+BENCH = json.loads((REPO / "BENCHMARK.json").read_text())
+EXISTING = [m for m in BENCH["per_layer"] if m["name"] not in NEW]
+
+
+def _cell_view(trace, metric):
+    """What ``metric``'s reader sees in the first cell that lists it, over
+    the recorded request."""
+    cell = harness.load_cell(metric["workloads"][0], REPO)
+    peak = json.loads((REPO / "chipbench" / "peaks.json").read_text())["TPU v5 lite"]
+    return harness.View(trace, SimpleNamespace(items=[None]), cell.config, cell.traffic,
+                        peak, 1)
+
+
+@pytest.fixture(scope="module")
+def recorded():
+    return json.load(gzip.open(FIXTURES / "scoped" / "decode_request_v5e.json.gz", "rt"))
+
+
+@pytest.mark.parametrize("metric", EXISTING, ids=[m["name"] for m in EXISTING])
+def test_existing_readers_read_the_same_with_scopes(recorded, metric):
+    """Each per-layer metric that the harness read with ``trace.Trace``
+    reads the same from ``scopes.ScopedTrace`` over the same trace."""
+    plain = [e[:5] for e in recorded if e[0] != "host" or e[2] in tr.ANNOTATIONS]
+    before = read(metric["name"], _cell_view(tr.Trace(plain), metric))
+    after = read(metric["name"], _cell_view(scopes.ScopedTrace(recorded), metric))
+    assert after == before
+
+
+@pytest.mark.parametrize("name", NEW)
+def test_new_readers_are_listed_and_read_a_number(recorded, name):
+    """The five span and scope readers are per-layer metrics of
+    ``BENCHMARK.json``; each reads a number where its cell's program runs:
+    the serving ones on the recorded decode request, the training one on
+    the hand-made training step."""
+    metric = next(m for m in BENCH["per_layer"] if m["name"] == name)
+    events = TRAIN if name.endswith(".train") else recorded
+    value = read(name, _cell_view(scopes.ScopedTrace(events), metric))
+    assert isinstance(value, float) and value > 0

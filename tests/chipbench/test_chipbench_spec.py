@@ -65,3 +65,24 @@ def test_cells(w):
     assert "setup_s" in e2e and len(e2e) >= 2
     layer = [m for m in BENCH["per_layer"] if w["name"] in m.get("workloads", [w["name"]])]
     assert layer and all(m["moves"] in e2e for m in layer)
+
+
+#: The numbers each traffic kind's driver compares, by the traffic key
+#: that turns each on.
+CHECKS = {"closed_batches": {"logit_gap": "check_requests", "sample_z": "check_sampled"},
+          "train_steps": {"loss_gap": "checked_steps", "grad_gap": "checked_steps",
+                          "update_gap": "checked_steps"}}
+
+
+@pytest.mark.parametrize("w", BENCH["workloads"], ids=[w["name"] for w in BENCH["workloads"]])
+def test_every_number_compared_has_its_limit(w):
+    """Each number a cell's traffic turns on has a limit in the cell's
+    limits file, set between the readings it was set from."""
+    traffic = json.loads((REPO / "chipbench" / "traffic" / f"{w['traffic']}.json").read_text())
+    limits = json.loads((REPO / "chipbench" / "limits" / f"{w['name']}.json").read_text())
+    on = {n for n, key in CHECKS[traffic["kind"]].items() if traffic.get(key, 0) > 0}
+    assert on and on <= set(limits), (on, set(limits))
+    for n in on:
+        lim = limits[n]
+        assert lim["lower"] < lim["limit"] < lim["upper"], (n, lim)
+        assert max(lim["program"]) == lim["lower"], n
