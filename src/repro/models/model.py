@@ -68,19 +68,28 @@ def _readout(params, cfg: ModelConfig, x):
         return L.linear(params["head"], x, dt)
 
 
+def compute_weights(params, cfg: ModelConfig):
+    """The working weights: every weight matrix (ndim >= 2) in
+    ``param_dtype`` cast to the compute ``dtype``; every other leaf (norm
+    gains, biases, leaves already cast) as it is.  ``forward`` applies it
+    to the weights it is given; a caller that never updates its weights
+    (serving) applies it once and passes the result, which then casts
+    nothing."""
+    dt, pdt = jnp.dtype(cfg.dtype), jnp.dtype(cfg.param_dtype)
+    if pdt == dt:
+        return params
+    return jax.tree.map(
+        lambda p: p.astype(dt) if p.ndim >= 2 and p.dtype == pdt else p,
+        params)
+
+
 def _cast_once(params, cfg: ModelConfig):
     """Materialize the bf16 working copy of every weight matrix BEFORE the
     layer scan (one local convert per shard) so FSDP all-gathers move bf16,
     not fp32 — §Perf iteration 1.  1-D params (norms, biases) stay fp32;
     the cast is differentiable, so fp32 masters receive exact grads."""
-    dt = jnp.dtype(cfg.dtype)
-    if jnp.dtype(cfg.param_dtype) == dt:
-        return params
     with jax.named_scope("weight_cast"):
-        return jax.tree.map(
-            lambda p: p.astype(dt) if (p.ndim >= 2 and
-                                       p.dtype == jnp.dtype(cfg.param_dtype))
-            else p, params)
+        return compute_weights(params, cfg)
 
 
 def forward(params, cfg: ModelConfig, batch: dict, cache=None,

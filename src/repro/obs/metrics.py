@@ -18,8 +18,9 @@ Metric names are dotted strings (see the README glossary):
   snapshotted from ``perf.memo.stats()`` when a session closes.
 * ``tune.*`` — cost-oracle batch throughput and search-rung progress.
 * ``serve.*`` — the engine's ``serve.cache.donated`` (whether decode
-  steps update the KV cache in place) and the serving simulator's
-  ``serve.sim.*``.
+  steps update the KV cache in place) and ``serve.weights.cast_at_build``
+  (bytes of weights cast to the compute dtype when it was built), and the
+  serving simulator's ``serve.sim.*``.
 * ``span.<name>.seconds`` — wall-time histograms from ``obs.spans``.
 
 Like ``record``, this module imports nothing from ``repro``.

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import zlib
 from dataclasses import dataclass
+from functools import partial
 
 import numpy as np
 
@@ -20,7 +21,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.kernels import ops as kops
-from repro.models.model import forward
+from repro.models.model import compute_weights, forward
 from repro.models.transformer import init_stack_cache
 from repro.obs import metrics as _obs_metrics
 from repro.obs.spans import span as _obs_span
@@ -55,6 +56,24 @@ def make_prefill(cfg: ModelConfig):
     return prefill
 
 
+def _working_weights(cfg: ModelConfig, params):
+    """``(weights, n)``: the weights ``forward`` computes with, derived once
+    from ``params`` by ``compute_weights``, and the bytes of the leaves it
+    cast (0 where none needed casting, and ``params`` is returned as it
+    is).  Arrays are cast by one jitted program into new buffers, so the
+    caller's tree is neither altered nor deleted; shapes
+    (``jax.ShapeDtypeStruct``) give the working weights' shapes."""
+    cast = partial(compute_weights, cfg=cfg)
+    shapes = jax.eval_shape(cast, params)
+    n = sum(p.size * jnp.dtype(p.dtype).itemsize for p, w in zip(
+        jax.tree.leaves(params), jax.tree.leaves(shapes)) if w.dtype != p.dtype)
+    if not n:
+        return params, 0
+    if any(isinstance(p, jax.ShapeDtypeStruct) for p in jax.tree.leaves(params)):
+        return shapes, n
+    return jax.jit(cast)(params), n
+
+
 def _mix32(*words: int) -> int:
     """Fold a tuple of ints into one well-scrambled uint32 stream seed
     (murmur3-finalizer avalanche per word).  Pure Python with explicit
@@ -79,6 +98,10 @@ class GenerationResult:
 class ServeEngine:
     """Batched greedy/temperature decoding over a fixed slot set.
 
+    It serves from ``weights``: ``params`` with every weight matrix cast
+    once, at build, to the compute dtype (``models.model.compute_weights``).
+    The caller's ``params`` are neither altered nor deleted.
+
     ``autotune=True`` flips a process-wide kernel-config default (see
     ``__init__``); use the engine as a context manager or call
     :meth:`close` to restore it.
@@ -89,7 +112,6 @@ class ServeEngine:
                  autotune: bool = False, power_cap_mw: float | None = None,
                  persist_tuned_defaults: bool = False, system=None):
         self.cfg = cfg
-        self.params = params
         self.max_len = max_len
         self.batch = batch
         self.temperature = temperature
@@ -150,6 +172,13 @@ class ServeEngine:
                         name: sys_tuner.operating_point(
                             name, n_clusters=system.n_clusters)
                         for name in ("softmax", "prng")}
+        # Serving never updates its weights, so they are cast to the compute
+        # dtype once, here, and the programs below cast nothing per call.
+        # An engine without params (a placeholder) only checks its inputs.
+        self.weights = None
+        if params is not None:
+            self.weights, n = _working_weights(cfg, params)
+            _obs_metrics.set_gauge("serve.weights.cast_at_build", n)
         # Both programs take the cache donated and update it in place.
         self._prefill = jax.jit(make_prefill(cfg), donate_argnums=1)
         self._step = jax.jit(make_serve_step(cfg), donate_argnums=1)
@@ -236,7 +265,7 @@ class ServeEngine:
             with _obs_span("serve.cache_init"):
                 cache = make_cache(self.cfg, B, self.max_len)
             with _obs_span("serve.prefill"):
-                logits, cache = self._prefill(self.params, cache, toks)
+                logits, cache = self._prefill(self.weights, cache, toks)
             out = [toks]
             for i in range(n_steps):
                 tok = self._sample(logits, i, slot_seeds)[:, None]
@@ -244,7 +273,7 @@ class ServeEngine:
                 if i + 1 < n_steps:
                     with _obs_span("serve.decode_step"):
                         given = cache
-                        logits, cache = self._step(self.params, cache, tok,
+                        logits, cache = self._step(self.weights, cache, tok,
                                                    jnp.int32(plen + i))
                     if i == 0:
                         # 0 where a backend or caller keeps the cache

@@ -87,6 +87,21 @@ def test_engine_programs_keep_scan_and_attention_scopes(dense, program):
                for n in names)
 
 
+@pytest.mark.parametrize("program", ["serve_step", "prefill"])
+def test_engine_programs_cast_no_weights(dense, program):
+    """Lowered on the engine's working weights, cast once when it was
+    built, the serve step and the prefill hold no op under
+    ``weight_cast``; on the fp32 params they do
+    (``test_dense_programs_carry_scopes``)."""
+    from repro.serve.engine import ServeEngine
+    cfg, params = dense
+    eng = ServeEngine(cfg, params, max_len=32, batch=2)
+    assert "weight_cast" in _scopes(_lower(dense, program))
+    got = _scopes(_lower((cfg, eng.weights), program))
+    assert "weight_cast" not in got
+    assert set(DENSE) - {"weight_cast"} <= got
+
+
 def test_backward_carries_forward_scopes(dense):
     """In the compiled train step, the op_name metadata that a trace
     carries puts the backward's ops under the forward's scopes."""

@@ -462,6 +462,26 @@ class TestServeEngine:
             assert 0 < cost.power_mw <= 250.0
         assert eng.system_plan is None
 
+    @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+    def test_weights_cast_at_build_gauge(self, dtype):
+        """``serve.weights.cast_at_build`` holds the bytes of the fp32
+        matrices the engine cast when it was built: all of them where the
+        compute dtype is bf16, none where it equals ``param_dtype``."""
+        import jax
+
+        from repro.configs import load_config
+        from repro.models.model import init_params
+        from repro.serve.engine import ServeEngine
+
+        cfg = load_config("jamba-v0.1-52b", "smoke").replace(dtype=dtype)
+        params = init_params(cfg, jax.random.PRNGKey(0))
+        with obs.session(trace=False, metrics=True) as sess:
+            ServeEngine(cfg, params, max_len=16, batch=2)
+        matrices = sum(p.nbytes for p in jax.tree.leaves(params) if p.ndim >= 2)
+        assert matrices > 0 and cfg.param_dtype == "float32"
+        want = matrices if dtype == "bfloat16" else 0
+        assert sess.metrics()["serve.weights.cast_at_build"]["value"] == want
+
     @pytest.mark.parametrize("temperature", [0.0, 0.8])
     def test_generate_spans_each_host_step(self, tmp_path, temperature):
         """One ``generate`` call marks its host steps in order, one span

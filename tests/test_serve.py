@@ -96,6 +96,74 @@ class TestEngineDonation:
             assert got == want, (name, got)
 
 
+@pytest.fixture(scope="module", params=["olmo-1b", "jamba-v0.1-52b"])
+def bf16_on_fp32(request):
+    """A dense OLMo-style config and a hybrid (SSM + MoE) one, at smoke
+    size, computing in bf16 on fp32 weights as served on the chip."""
+    from repro.configs import load_config
+    from repro.models.model import init_params
+
+    cfg = load_config(request.param, "smoke").replace(dtype="bfloat16")
+    return cfg, init_params(cfg, jax.random.PRNGKey(0))
+
+
+class TestEngineWorkingWeights:
+    @pytest.mark.parametrize("temperature", [0.0, 0.8])
+    def test_tokens_equal_the_programs_on_fp32_params(self, bf16_on_fp32,
+                                                      temperature):
+        """An engine serving from weights cast once returns, bit for bit,
+        the tokens of the prefill and step programs run on the caller's
+        fp32 params, which cast on every call; their logits are equal
+        too."""
+        from repro.serve.engine import make_prefill, make_serve_step
+
+        cfg, params = bf16_on_fp32
+        eng = ServeEngine(cfg, params, max_len=16, batch=2,
+                          temperature=temperature, seed=5)
+        prompts = np.asarray(jax.random.randint(
+            jax.random.PRNGKey(1), (2, 6), 0, cfg.vocab_size), np.int32)
+        n = 5
+        got = eng.generate(prompts, n).tokens
+
+        prefill = jax.jit(make_prefill(cfg))
+        step = jax.jit(make_serve_step(cfg))
+        seeds = eng._slot_seeds(prompts)
+        toks = jnp.asarray(prompts)
+        logits, cache = prefill(params, make_cache(cfg, 2, 16), toks)
+        cast, cast_cache = prefill(eng.weights, make_cache(cfg, 2, 16), toks)
+        out = [prompts]
+        for i in range(n):
+            np.testing.assert_array_equal(np.asarray(cast), np.asarray(logits))
+            tok = eng._sample(logits, i, seeds)[:, None]
+            out.append(np.asarray(tok))
+            if i + 1 < n:
+                index = jnp.int32(prompts.shape[1] + i)
+                logits, cache = step(params, cache, tok, index)
+                cast, cast_cache = step(eng.weights, cast_cache, tok, index)
+        np.testing.assert_array_equal(got, np.concatenate(out, 1))
+
+    def test_matrices_cast_and_the_callers_tree_kept(self, bf16_on_fp32):
+        """The working weights hold every matrix in the compute dtype and
+        every other leaf as the caller's; the caller's tree is neither
+        deleted nor altered by building the engine and serving."""
+        cfg, params = bf16_on_fp32
+        before = jax.tree.map(np.array, params)
+        eng = ServeEngine(cfg, params, max_len=16, batch=2)
+        eng.generate(np.ones((2, 4), np.int32), 3)
+        for p, w, b in zip(jax.tree.leaves(params), jax.tree.leaves(eng.weights),
+                           jax.tree.leaves(before), strict=True):
+            assert not p.is_deleted()
+            assert p.dtype == jnp.float32
+            np.testing.assert_array_equal(np.asarray(p), b)
+            if p.ndim >= 2:
+                assert w.dtype == jnp.bfloat16
+                np.testing.assert_array_equal(
+                    np.asarray(w), np.asarray(b.astype(jnp.bfloat16)))
+            else:
+                assert w.dtype == p.dtype
+                np.testing.assert_array_equal(np.asarray(w), b)
+
+
 class TestEngineTunedDefaultScope:
     def test_autotune_restores_process_default_on_close(self):
         # Regression: autotune=True flipped kops.set_tuned_defaults(True)

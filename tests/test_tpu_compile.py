@@ -130,11 +130,12 @@ def _top_level_ops(text: str, dims: str) -> list[tuple[str, str]]:
 
 def test_serve_step_updates_cache_in_place(one_chip, monkeypatch):
     """The engine's decode step, compiled for a v5e at OLMo-1B's widths
-    (2 of 16 layers, batch 8, 2048 slots) with the Pallas softmax, moves
-    no whole layer or stack of the KV cache: the only cache-sized ops are
-    the two in-place writes of the new token's K and V.  Left to choose
-    the carried cache's layout, the compiler would relay out each stack on
-    entry and exit."""
+    (2 of 16 layers, batch 8, 2048 slots) with the Pallas softmax on the
+    engine's own working weights, moves no whole layer or stack of the KV
+    cache: the only cache-sized ops are the two in-place writes of the new
+    token's K and V.  Left to choose the carried cache's layout, the
+    compiler would relay out each stack on entry and exit.  Nor does it
+    convert any weight: the engine cast them once when it was built."""
     from repro.configs import load_config
     from repro.models.model import init_params
     from repro.serve.engine import ServeEngine, make_cache
@@ -142,16 +143,19 @@ def test_serve_step_updates_cache_in_place(one_chip, monkeypatch):
     monkeypatch.setattr(ops, "_interpret", lambda: False)
     cfg = load_config("olmo-1b").replace(
         n_layers=2, layer_types="aa", dtype="bfloat16", softmax_impl="pallas")
-    params = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
-    cache = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        jax.eval_shape(lambda: make_cache(cfg, 8, 2048)))
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            tree)
+
+    params = on_chip(jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    cache = on_chip(jax.eval_shape(lambda: make_cache(cfg, 8, 2048)))
     tok = jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=one_chip)
     index = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     eng = ServeEngine(cfg, params, max_len=2048, batch=8)
-    text = eng._step.lower(params, cache, tok, index).compile().as_text()
+    weights = on_chip(eng.weights)
+    text = eng._step.lower(weights, cache, tok, index).compile().as_text()
     assert "tpu_custom_call" in text
     layer = "8,2048,16,128"
     assert not _top_level_ops(text, layer)
@@ -160,3 +164,11 @@ def test_serve_step_updates_cache_in_place(one_chip, monkeypatch):
     assert len(stack) == 2 and all(
         op == "fusion" and "dynamic-update-slice" in name
         for name, op in stack), stack
+    # a convert's operand has its output's shape; the compiled text gives
+    # only the output's
+    shapes = {",".join(map(str, p.shape)) for p in jax.tree.leaves(params)
+              if p.ndim >= 2}
+    converts = [m for m in re.findall(r"= \w+\[([\d,]*)\]\S* convert\(", text)
+                if m in shapes]
+    assert {p.dtype for p in jax.tree.leaves(params)} == {jnp.dtype("float32")}
+    assert not converts, converts
