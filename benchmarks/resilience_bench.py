@@ -80,8 +80,10 @@ def _nofault_reproduction(pricer) -> dict:
         from benchmarks import serve_bench   # python -m benchmarks.run
     except ImportError:
         import serve_bench                   # run as a script
-    from repro.serve import (SloSpec, StaticPolicy, make_faults, make_trace,
-                             simulate)
+    from repro.resilience.faults import make_faults
+    from repro.serve.policies import StaticPolicy
+    from repro.serve.sim import SloSpec, simulate
+    from repro.serve.traffic import make_trace
     trace = make_trace(serve_bench.TRACE_SPEC,
                        duration_ms=serve_bench.SMOKE_DURATION_MS,
                        seed=serve_bench.TRACE_SEED)
@@ -104,9 +106,11 @@ def generate(smoke: bool = False, seed: int = TRACE_SEED) -> dict:
     """Run the chaos scenario naive vs failover, plus the determinism and
     no-fault-reproduction gates."""
     global _LAST_DOC
-    from repro.serve import (FailoverPolicy, RetryPolicy, ServicePricer,
-                             SloSpec, SlotPlan, StaticPolicy, make_faults,
-                             make_trace, simulate)
+    from repro.resilience.failover import FailoverPolicy, RetryPolicy
+    from repro.resilience.faults import make_faults
+    from repro.serve.policies import StaticPolicy
+    from repro.serve.sim import ServicePricer, SloSpec, SlotPlan, simulate
+    from repro.serve.traffic import make_trace
 
     duration = SMOKE_DURATION_MS if smoke else DURATION_MS
     trace = make_trace(TRACE_SPEC, duration_ms=duration, seed=seed)

@@ -74,8 +74,9 @@ def generate(smoke: bool = False, seed: int = TRACE_SEED) -> dict:
     the whole section costs well under a second after plan pricing.
     """
     global _LAST_DOC
-    from repro.serve import (POLICIES, ModelPredictivePolicy, ServicePricer,
-                             SloSpec, make_trace, simulate)
+    from repro.serve.policies import POLICIES, ModelPredictivePolicy
+    from repro.serve.sim import ServicePricer, SloSpec, simulate
+    from repro.serve.traffic import make_trace
 
     duration = SMOKE_DURATION_MS if smoke else DURATION_MS
     trace = make_trace(TRACE_SPEC, duration_ms=duration, seed=seed)

@@ -10,8 +10,9 @@ Run:  PYTHONPATH=src python examples/serve_sim_demo.py
 """
 
 from repro.api import Tuner
-from repro.serve import (POLICIES, ServicePricer, SloSpec, make_trace,
-                         simulate)
+from repro.serve.policies import POLICIES
+from repro.serve.sim import ServicePricer, SloSpec, simulate
+from repro.serve.traffic import make_trace
 
 SPEC = ("bursty:rate=860,burst=2.33,period_ms=1200,duty=0.22,"
         "kernel=softmax,elems=65536")

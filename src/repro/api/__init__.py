@@ -53,9 +53,8 @@ _DEFAULT_TUNER: "Tuner | None" = None
 
 def default_tuner() -> Tuner:
     """The shared process-wide :class:`Tuner` (default target, persistent
-    cache) — what ``kernels.ops`` tiling defaults and
-    ``copift.make_plan(tune=True)`` consult, so every consumer hits one
-    cache and one cost oracle."""
+    cache) — what ``copift.make_plan(tune=True)`` consults, so every
+    consumer hits one cache and one cost oracle."""
     global _DEFAULT_TUNER
     if _DEFAULT_TUNER is None:
         _DEFAULT_TUNER = Tuner()

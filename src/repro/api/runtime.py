@@ -1,13 +1,12 @@
 """Scoped runtime configuration — ``repro.api.config``.
 
-The kernels' impl dispatch (``auto``/``pallas``/``reference``) and the
-tuned-tiling defaults used to be module-level mutable globals — process-wide
-state that concurrent benchmarks could race and that leaked across test
-boundaries.  ``config`` is a context manager over ContextVars, so the
-override is visible exactly within the ``with`` block (and within the
-current thread/task — a parallel benchmark keeps its own view):
+``config`` overrides the kernels' impl dispatch
+(``auto``/``pallas``/``reference``) through a ContextVar, so the override
+is visible exactly within the ``with`` block and the current thread/task:
+a parallel benchmark keeps its own view, and nothing leaks across test
+boundaries.
 
-    with repro.api.config(impl="reference", tuned_defaults=True):
+    with repro.api.config(impl="reference"):
         y = repro.api.kernel("expf").run(x)
 
 The import of the kernel stack (and therefore jax) is deferred to the
@@ -20,15 +19,13 @@ import contextlib
 
 
 @contextlib.contextmanager
-def config(impl: str | None = None, tuned_defaults: bool | None = None):
-    """Scoped kernel-runtime override.
+def config(impl: str | None = None):
+    """Scoped kernel-runtime override: ``impl`` is the 'auto' | 'pallas' |
+    'reference' kernel dispatch.
 
-    ``impl``            'auto' | 'pallas' | 'reference' kernel dispatch;
-    ``tuned_defaults``  let ``repro.tune`` pick default block tilings.
-
-    ``None`` leaves a setting untouched.  Settings restore on exit even on
+    ``None`` leaves the setting untouched.  It is restored on exit even on
     error; nesting composes (inner scopes win).
     """
     from repro.kernels import ops as kops
-    with kops.overrides(impl=impl, tuned_defaults=tuned_defaults):
+    with kops.overrides(impl=impl):
         yield

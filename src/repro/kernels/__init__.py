@@ -8,9 +8,8 @@ execution on CPU; TPU is the compilation target).
 """
 
 from repro.kernels import ops, ref
-from repro.kernels.ops import (exp, log, mc_pi, mc_poly, overrides,
-                               set_impl, set_tuned_defaults, softmax,
+from repro.kernels.ops import (exp, log, mc_pi, mc_poly, overrides, softmax,
                                uniform)
 
 __all__ = ["ops", "ref", "exp", "log", "mc_pi", "mc_poly", "overrides",
-           "set_impl", "set_tuned_defaults", "softmax", "uniform"]
+           "softmax", "uniform"]

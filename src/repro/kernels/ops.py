@@ -21,7 +21,6 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
-import functools
 
 import numpy as np
 
@@ -40,111 +39,31 @@ LANES = _exp.LANES
 
 _IMPLS = ("auto", "pallas", "reference")
 
-#: Two layers of configuration.  Scoped overrides (``overrides`` /
-#: ``repro.api.config``) live in ContextVars: a ``with`` block in one
-#: thread or asyncio task cannot race a concurrent benchmark reading the
-#: default in another — the failure mode the old mutable globals invited.
-#: The *process-wide defaults* underneath (``set_impl`` /
-#: ``set_tuned_defaults``) stay plain module globals, visible from every
-#: thread: ``ServeEngine(autotune=True)`` sets them in ``__init__`` and
-#: the lazily-resolved jit traces must still see them when ``generate()``
-#: runs on a request thread (new threads start with empty contexts, so a
-#: ContextVar default would silently vanish there).
-_IMPL_DEFAULT = "auto"
-_TUNED_DEFAULT = False
-_IMPL_VAR: contextvars.ContextVar[str | None] = \
-    contextvars.ContextVar("repro_kernels_impl", default=None)
-_TUNED_VAR: contextvars.ContextVar[bool | None] = \
-    contextvars.ContextVar("repro_kernels_tuned_defaults", default=None)
+#: The scoped impl override (``overrides`` / ``repro.api.config``) lives in
+#: a ContextVar: a ``with`` block in one thread or asyncio task cannot race
+#: a concurrent benchmark reading the default in another.
+_IMPL_VAR: contextvars.ContextVar[str] = \
+    contextvars.ContextVar("repro_kernels_impl", default="auto")
 
 
 def current_impl() -> str:
-    """The impl default in effect: the innermost scoped override, else the
-    process-wide default."""
-    v = _IMPL_VAR.get()
-    return _IMPL_DEFAULT if v is None else v
-
-
-def tuned_defaults_enabled() -> bool:
-    v = _TUNED_VAR.get()
-    return _TUNED_DEFAULT if v is None else v
-
-
-def set_impl(impl: str) -> None:
-    """Set the process-wide impl default ('auto' | 'pallas' |
-    'reference'), visible from every thread.  Prefer the scoped
-    ``repro.api.config(impl=...)`` where a ``with`` block suffices."""
-    global _IMPL_DEFAULT
-    if impl not in _IMPLS:
-        raise ValueError(f"unknown impl {impl!r}; expected one of {_IMPLS}")
-    _IMPL_DEFAULT = impl
-
-
-def set_tuned_defaults(enable: bool = True) -> bool:
-    """Let the autotuner (``repro.tune``) pick the kernels' default block
-    tiling — the process-wide default, visible from every thread.  Entry
-    points called without an explicit ``block_rows`` then scale the module
-    default by the tuned block's share of the Table-I cap (the analytic
-    model's block choice transferred onto the Pallas grid); tuned results
-    come from the persistent tune cache, so the first call per kernel
-    searches and the rest are free.  Prefer the scoped
-    ``repro.api.config(...)`` unless the enablement must outlive a
-    ``with`` block (e.g. ``ServeEngine`` setup, whose jit traces resolve
-    tilings lazily at first generate, possibly on another thread).
-
-    Returns the *previous* process-wide default, so callers that must use
-    the persistent setter can still restore the state they found
-    (``ServeEngine.close()`` does exactly this)."""
-    global _TUNED_DEFAULT
-    prev = _TUNED_DEFAULT
-    _TUNED_DEFAULT = bool(enable)
-    _tuned_block_rows.cache_clear()
-    return prev
+    """The impl default in effect: the innermost scoped override, else
+    ``"auto"``."""
+    return _IMPL_VAR.get()
 
 
 @contextlib.contextmanager
-def overrides(impl: str | None = None, tuned_defaults: bool | None = None):
-    """Scoped kernel-config override — the engine behind
-    ``repro.api.config``.  ``None`` leaves a setting untouched; values are
-    restored (and the tuned-tiling memo dropped) on exit, even on error."""
-    tokens = []
-    if impl is not None:
-        if impl not in _IMPLS:
-            raise ValueError(f"unknown impl {impl!r}; expected one of "
-                             f"{_IMPLS}")
-        tokens.append((_IMPL_VAR, _IMPL_VAR.set(impl)))
-    if tuned_defaults is not None:
-        tokens.append((_TUNED_VAR, _TUNED_VAR.set(bool(tuned_defaults))))
-        _tuned_block_rows.cache_clear()
+def overrides(impl: str | None = None):
+    """Scoped impl override — the engine behind ``repro.api.config``.
+    ``None`` leaves the impl untouched; it is restored on exit, even on
+    error."""
+    if impl is not None and impl not in _IMPLS:
+        raise ValueError(f"unknown impl {impl!r}; expected one of {_IMPLS}")
+    token = _IMPL_VAR.set(impl or current_impl())
     try:
         yield
     finally:
-        for var, token in reversed(tokens):
-            var.reset(token)
-        if tuned_defaults is not None:
-            _tuned_block_rows.cache_clear()
-
-
-@functools.lru_cache(maxsize=None)
-def _tuned_block_rows(kernel: str, default_rows: int) -> int:
-    # The facade's default tuner: one shared cache + cost oracle across
-    # ops/copift/engine consumers (repro.api.default_tuner).
-    from repro.api import default_tuner
-    tuner = default_tuner()
-    w = tuner._workload(kernel)
-    res = tuner.block(w)          # only the block transfers to the tiling
-    return max(1, round(default_rows * res.best.block / w.max_block))
-
-
-def _resolve_rows(kernel: str, explicit: int | None, default_rows: int) -> int:
-    if explicit is not None:
-        return explicit
-    if tuned_defaults_enabled():
-        try:
-            return _tuned_block_rows(kernel, default_rows)
-        except (ImportError, KeyError):
-            pass
-    return default_rows
+        _IMPL_VAR.reset(token)
 
 
 def _resolve(impl: str | None) -> str:
@@ -176,7 +95,7 @@ def exp(x: jax.Array, impl: str | None = None,
     """COPIFT exp (glibc-expf-style), elementwise, any shape."""
     if _resolve(impl) == "reference":
         return _ref.exp_ref(x).astype(x.dtype)
-    block_rows = _resolve_rows("expf", block_rows, _exp.DEFAULT_BLOCK_ROWS)
+    block_rows = block_rows or _exp.DEFAULT_BLOCK_ROWS
     tiled, n = _tile_1d(x, block_rows)
     y = _exp.exp_2d(tiled, block_rows=block_rows, interpret=_interpret())
     return _untile(y, n, x.shape, x.dtype)
@@ -187,7 +106,7 @@ def log(x: jax.Array, impl: str | None = None,
     """COPIFT log (glibc-logf-style, ISSR table gather), positive normals."""
     if _resolve(impl) == "reference":
         return _ref.log_ref(x).astype(x.dtype)
-    block_rows = _resolve_rows("logf", block_rows, _log.DEFAULT_BLOCK_ROWS)
+    block_rows = block_rows or _log.DEFAULT_BLOCK_ROWS
     tiled, n = _tile_1d(x, block_rows)
     tiled = jnp.where(tiled <= 0, 1.0, tiled)   # padding lanes → ln(1)=0
     y = _log.log_2d(tiled, block_rows=block_rows, interpret=_interpret())
@@ -207,8 +126,7 @@ def softmax(x: jax.Array, axis: int = -1, impl: str | None = None,
         return jnp.moveaxis(y, -1, axis)
     cols = x.shape[-1]
     # TPU blocks span a multiple of 8 rows; shrink toward 8 for long rows.
-    br = _resolve_rows("softmax", block_rows, 8)
-    br = max(8, min(br, _softmax.MAX_BLOCK_ELEMS // cols) // 8 * 8)
+    br = max(8, min(block_rows or 8, _softmax.MAX_BLOCK_ELEMS // cols) // 8 * 8)
     interpret = _interpret()
 
     def rows_softmax(xs):
@@ -232,7 +150,7 @@ def uniform(seed: int | jax.Array, shape: tuple[int, ...],
         u = _prng.uniform_counter_ref(int(seed) if not hasattr(seed, "dtype")
                                       else seed, (rows, LANES), kind=kind)
         return u.reshape(-1)[:n].reshape(shape)
-    block_rows = _resolve_rows("prng", block_rows, _prng.DEFAULT_BLOCK_ROWS)
+    block_rows = block_rows or _prng.DEFAULT_BLOCK_ROWS
     tile = block_rows * LANES
     rows = (-(-n // tile)) * block_rows
     u = _prng.uniform_2d(jnp.uint32(seed), kind=kind, block_rows=block_rows,

@@ -36,8 +36,8 @@ def main(argv=None):
     enable_compile_cache()
     cfg = load_config(args.arch, args.variant)
     if cfg.is_encoder_only:
-        raise SystemExit(f"{cfg.name} is encoder-only: no decode step "
-                         "(DESIGN.md §5)")
+        raise SystemExit(f"{cfg.name} is encoder-only: it attends both ways "
+                         "over the whole input, so it has no decode step")
     params = init_params(cfg, jax.random.PRNGKey(args.seed))
     if args.params:
         params, _ = ckpt.load(args.params, like=params)

@@ -66,16 +66,7 @@ def main(argv=None):
     ap.add_argument("--ckpt-every", type=int, default=20)
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--metrics-out", default="")
-    ap.add_argument("--autotune", action="store_true",
-                    help="let repro.tune pick the COPIFT kernel tilings "
-                         "(cached; first run searches, later runs are free)")
     args = ap.parse_args(argv)
-
-    if args.autotune:
-        from repro.kernels import ops as kops
-        kops.set_tuned_defaults(True)
-        print("[tune] kernel block tilings autotuned "
-              "(repro.api.default_tuner cache)")
 
     enable_compile_cache()
     cfg = load_config(args.arch, args.variant).replace(

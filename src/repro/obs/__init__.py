@@ -10,10 +10,11 @@ Three opt-in layers behind one front door (:func:`session`):
 * **metrics** (``obs.metrics``) — a process-wide counter/gauge/histogram
   registry fed by ``core.timing`` (stall split), ``cluster.contention`` /
   ``cluster.dma``, ``tune.cost`` / ``tune.search`` (oracle throughput,
-  rung progress), ``perf.memo`` (warmth) and ``serve.engine`` (autotune);
+  rung progress), ``perf.memo`` (warmth) and ``serve.engine`` (cache
+  donation, weights cast at build);
 * **spans** (``obs.spans``) — nested wall-time scopes with per-span memo
   provenance, wrapping ``api.evaluate``/``api.sweep``, tuner searches and
-  the serve engine's autotune.
+  the serve engine's host steps.
 
 Everything is zero-cost-by-default: disabled, the hooks reduce to a couple
 of ContextVar reads per *call* (never per instruction), gated < 5 % by

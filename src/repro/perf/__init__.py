@@ -1,8 +1,8 @@
 """``repro.perf`` — the timing-engine performance layer.
 
 The reproduction's evaluation pipeline is itself a workload: the tune cost
-oracle, ``api.evaluate``, ``api.sweep`` and the serve engine's autotune
-all bottom out in the pure-Python discrete-event simulator in
+oracle, ``api.evaluate``, ``api.sweep`` and the serving simulator all
+bottom out in the pure-Python discrete-event simulator in
 ``core.timing``, and the paper's Table-I exploration (and the Late
 Breaking Results follow-up) hinge on pricing large schedule spaces.  This
 package makes that pipeline fast *without changing a single cycle*:

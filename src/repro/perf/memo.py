@@ -1,7 +1,7 @@
 """Content-addressed simulation memo — the cache tier under ``core.timing``.
 
 Every consumer of the reproduction (the tune cost oracle, ``api.evaluate``,
-the cluster sweeps, the serve engine's autotune) bottoms out in the pure
+the cluster sweeps, the serving simulator) bottoms out in the pure
 Python discrete-event simulator (``_ssa_unroll`` → ``_list_schedule`` →
 ``_simulate_inorder_counts``), and before this layer re-ran it from scratch for
 every candidate — even though thousands of candidates share identical

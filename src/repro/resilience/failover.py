@@ -47,11 +47,8 @@ from repro.obs import metrics as _obs_metrics
 from repro.obs import record as _obs_record
 from repro.obs.spans import span as _obs_span
 from repro.resilience.faults import FaultTrace
-
-# NOTE: repro.serve imports are function-local throughout — repro.serve
-# re-exports RetryPolicy/FailoverPolicy from this module, so the module
-# boundary must stay lazy in one direction (same rule as system.analytics
-# vs api.evaluate).
+from repro.serve.sim import (PERCENTILES, PolicyContext, SimReport,
+                             _nearest_rank)
 
 __all__ = ["RetryPolicy", "FailoverPolicy", "simulate_failover",
            "FAULT_LANE"]
@@ -174,8 +171,6 @@ def simulate_failover(trace, policy, *, slo, epoch_ms: float,
     ``serve.simulate`` whenever ``faults`` carries fail-stop events —
     arguments mirror ``simulate`` exactly; returns a
     ``serve.sim.SimReport``."""
-    from repro.serve.sim import (PERCENTILES, PolicyContext, SimReport,
-                                 _nearest_rank)
     pname = getattr(policy, "name", type(policy).__name__)
     n_cores = pricer.n_cores
     cores_per_cluster = pricer.cluster.n_cores

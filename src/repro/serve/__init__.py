@@ -1,30 +1,13 @@
-"""serve substrate (see DESIGN.md §4): the decode engine
-(``serve.engine``) plus the discrete-event serving simulator —
-arrival traces (``serve.traffic``), the event loop and service pricer
-(``serve.sim``), and autoscaling policies (``serve.policies``).
+"""Serving, in two stacks that share nothing but this package name.
 
-The engine is deliberately NOT imported here: it pulls in the model
-stack (jax tracing), while the simulator runs purely on the analytic
-cost models — ``from repro.serve import simulate`` must stay cheap.
+* ``serve.engine`` — the decode engine on the TPU stack: prefill and
+  single-token decode programs over the model stack, and ``ServeEngine``
+  for batched generation.
+* The discrete-event serving simulator on the analytic Snitch-cluster cost
+  models: arrival traces (``serve.traffic``), the event loop and service
+  pricer (``serve.sim``), and autoscaling policies (``serve.policies``).
+
+This module imports neither, so importing the engine loads no simulator
+module and the simulator loads no JAX model code.  Import the module you
+use.
 """
-
-from repro.resilience.failover import FailoverPolicy, RetryPolicy
-from repro.resilience.faults import FaultTrace, make_faults
-from repro.serve.policies import (POLICIES, ModelPredictivePolicy, Policy,
-                                  ReactivePolicy, StaticPolicy,
-                                  plan_for_rate, plan_grid)
-from repro.serve.sim import (PERCENTILES, PolicyContext, ServicePricer,
-                             SimReport, SloSpec, SlotPlan, simulate)
-from repro.serve.traffic import (TRACE_FAMILIES, Request, Trace,
-                                 make_trace)
-
-__all__ = [
-    "Request", "Trace", "make_trace", "TRACE_FAMILIES",
-    "SloSpec", "SlotPlan", "PolicyContext", "ServicePricer", "SimReport",
-    "simulate", "PERCENTILES",
-    "Policy", "StaticPolicy", "ReactivePolicy", "ModelPredictivePolicy",
-    "plan_grid", "plan_for_rate", "POLICIES",
-    # Resilience surface (re-exported: simulate(faults=..., retry=...)
-    # consumes these; repro.resilience is the home package).
-    "FaultTrace", "make_faults", "RetryPolicy", "FailoverPolicy",
-]

@@ -101,77 +101,15 @@ class ServeEngine:
     It serves from ``weights``: ``params`` with every weight matrix cast
     once, at build, to the compute dtype (``models.model.compute_weights``).
     The caller's ``params`` are neither altered nor deleted.
-
-    ``autotune=True`` flips a process-wide kernel-config default (see
-    ``__init__``); use the engine as a context manager or call
-    :meth:`close` to restore it.
     """
 
     def __init__(self, cfg: ModelConfig, params, max_len: int = 256,
-                 batch: int = 4, temperature: float = 0.0, seed: int = 0,
-                 autotune: bool = False, power_cap_mw: float | None = None,
-                 persist_tuned_defaults: bool = False, system=None):
+                 batch: int = 4, temperature: float = 0.0, seed: int = 0):
         self.cfg = cfg
         self.max_len = max_len
         self.batch = batch
         self.temperature = temperature
         self.seed = seed
-        self.autotune = autotune
-        self.power_cap_mw = power_cap_mw
-        self.system = system
-        self.operating_plan = None
-        self.system_plan = None
-        self._prev_tuned: bool | None = None
-        self._persist_tuned = persist_tuned_defaults
-        self._closed = False
-        if power_cap_mw is not None and not autotune:
-            raise ValueError(
-                f"power_cap_mw={power_cap_mw} only constrains the autotuned "
-                f"operating plan, but autotune=False, so the cap would be "
-                f"silently ignored. Either pass autotune=True so the engine "
-                f"searches an operating plan under the cap, or drop "
-                f"power_cap_mw to run with the static kernel defaults.")
-        if autotune:
-            # Engine setup is where tuning pays: the softmax/PRNG kernels
-            # run every decode step, so let the facade's tuner pick their
-            # tiling once (cached) before the jit traces below bake it in.
-            # The context-scoped ``repro.api.config`` would not outlive
-            # __init__, while the traces resolve tilings lazily at the
-            # first generate() — so this uses the persistent setter and
-            # records the value it displaced; ``close()`` (or exiting the
-            # engine's ``with`` block) restores it, unless the caller
-            # opted out via ``persist_tuned_defaults=True``.
-            from repro import api
-            self._prev_tuned = kops.set_tuned_defaults(True)
-            # Also pick the cluster operating plan for the decode-hot
-            # kernels: the heterogeneous (DVFS-island) search with
-            # per-island block refinement, which never scores worse than
-            # the homogeneous ladder under the same power cap.  The whole
-            # search runs on the batched cost oracle over the repro.perf
-            # timing memo (tune.cost.evaluate_batch), so engine startup
-            # prices the full island x strategy x block space in well
-            # under a second instead of re-simulating per candidate.
-            # Advisory on this backend — `operating_plan` is what a
-            # Snitch-cluster deployment of the engine would pin.
-            tuner = api.Tuner(api.Target.homogeneous(
-                power_cap_mw=power_cap_mw))
-            with _obs_span("serve.autotune", power_cap_mw=power_cap_mw):
-                self.operating_plan = {
-                    name: tuner.operating_point(name, heterogeneous=True,
-                                                per_island_blocks=True)
-                    for name in ("softmax", "prng")}
-                if system is not None:
-                    # Manycore deployment: also size the part — cluster
-                    # count x DVFS point under the same (system) power
-                    # cap, priced through repro.system.  ``system`` here
-                    # is a SystemConfig whose cluster count is the upper
-                    # bound of the search.
-                    sys_tuner = api.Tuner(api.Target.system(
-                        system, power_cap_mw=power_cap_mw))
-                    self.system_plan = {
-                        name: sys_tuner.operating_point(
-                            name, n_clusters=system.n_clusters)
-                        for name in ("softmax", "prng")}
         # Serving never updates its weights, so they are cast to the compute
         # dtype once, here, and the programs below cast nothing per call.
         # An engine without params (a placeholder) only checks its inputs.
@@ -182,34 +120,6 @@ class ServeEngine:
         # Both programs take the cache donated and update it in place.
         self._prefill = jax.jit(make_prefill(cfg), donate_argnums=1)
         self._step = jax.jit(make_serve_step(cfg), donate_argnums=1)
-
-    # -- lifecycle ----------------------------------------------------------
-
-    def close(self) -> None:
-        """Undo the engine's process-wide side effect.
-
-        ``autotune=True`` enables tuned kernel defaults through the
-        persistent setter (the jit traces resolve tilings lazily,
-        possibly on another thread, so a scoped override cannot cover
-        them); ``close()`` restores whatever value that setter displaced,
-        so building an autotuned engine no longer flips the default for
-        every later caller in the process.  Idempotent.  The escape
-        hatch ``persist_tuned_defaults=True`` keeps the enablement alive
-        past ``close()`` — for setups that deliberately build one
-        throwaway engine to warm the process-wide tuned state.
-        """
-        if self._closed:
-            return
-        self._closed = True
-        if self._prev_tuned is not None and not self._persist_tuned:
-            kops.set_tuned_defaults(self._prev_tuned)
-
-    def __enter__(self) -> "ServeEngine":
-        return self
-
-    def __exit__(self, *exc) -> bool:
-        self.close()
-        return False
 
     # -- decoding -----------------------------------------------------------
 

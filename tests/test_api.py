@@ -13,6 +13,8 @@ methods, and per-island block tuning never scores worse than the
 shared-block plan under the same power cap.
 """
 
+import importlib
+import inspect
 import threading
 import warnings
 
@@ -24,6 +26,8 @@ from repro.core.analytics import TABLE_I
 from repro.core.energy import evaluate_energy
 from repro.core.kernels_isa import KERNELS, baseline_trace, copift_schedule
 from repro.core.timing import evaluate_kernel
+from repro.kernels import ops as kops
+from repro.serve.engine import ServeEngine
 
 #: Every numeric/structural field of a Report two evaluations must agree
 #: on for "bit-for-bit" (``strategy`` is a label, compared separately).
@@ -101,8 +105,9 @@ class TestFacadeParity:
 
 
 class TestShimsGone:
-    """The deprecation window closed: the pre-facade names no longer
-    exist anywhere (importing them is an error, not a warning)."""
+    """Removed names stay removed: the pre-facade shims, the kernels'
+    process-wide setters and the tuned-tiling keywords (importing one is
+    an error, passing one a ``TypeError``, neither a warning)."""
 
     def test_cluster_shims_removed(self):
         import repro.cluster as cluster
@@ -112,11 +117,45 @@ class TestShimsGone:
 
     def test_kernel_setter_shims_removed(self):
         import repro.kernels as kernels
-        from repro.kernels import ops as kops
         for name in ("set_default_impl", "enable_tuned_defaults"):
             assert not hasattr(kops, name)
             assert not hasattr(kernels, name)
             assert name not in kernels.__all__
+
+    @pytest.mark.parametrize("module", ["repro.kernels", "repro.kernels.ops"])
+    @pytest.mark.parametrize("name", [
+        "set_tuned_defaults", "tuned_defaults_enabled", "set_impl"])
+    def test_kernel_process_setters_removed(self, module, name):
+        """The kernel tiling is the kernel module's constant and the impl
+        is set only in a scope: no process-wide setter is left."""
+        mod = importlib.import_module(module)
+        assert not hasattr(mod, name)
+        assert name not in getattr(mod, "__all__", ())
+
+    @pytest.mark.parametrize("build", [
+        lambda: ServeEngine(object(), None, autotune=True),
+        lambda: ServeEngine(object(), None, power_cap_mw=250.0),
+        lambda: ServeEngine(object(), None, persist_tuned_defaults=True),
+        lambda: ServeEngine(object(), None, system=object()),
+        lambda: api.config(tuned_defaults=True),
+        lambda: kops.overrides(tuned_defaults=True),
+    ], ids=["engine-autotune", "engine-power_cap_mw",
+            "engine-persist_tuned_defaults", "engine-system",
+            "config-tuned_defaults", "overrides-tuned_defaults"])
+    def test_tuned_keywords_rejected(self, build):
+        with pytest.raises(TypeError, match="unexpected keyword"):
+            build()
+
+    def test_engine_takes_only_its_serving_arguments(self):
+        assert list(inspect.signature(ServeEngine).parameters) == [
+            "cfg", "params", "max_len", "batch", "temperature", "seed"]
+
+    def test_train_launcher_has_no_autotune_flag(self, capsys):
+        from repro.launch.train import main
+        with pytest.raises(SystemExit) as ei:
+            main(["--autotune", "--steps", "0"])
+        assert ei.value.code == 2
+        assert "unrecognized arguments: --autotune" in capsys.readouterr().err
 
 
 class TestTarget:
@@ -228,15 +267,12 @@ class TestConfigContextManager:
     def test_scoped_and_restored(self):
         from repro.kernels import ops as kops
         assert kops.current_impl() == "auto"
-        with api.config(impl="reference", tuned_defaults=True):
+        with api.config(impl="reference"):
             assert kops.current_impl() == "reference"
-            assert kops.tuned_defaults_enabled()
             with api.config(impl="pallas"):
                 assert kops.current_impl() == "pallas"
-                assert kops.tuned_defaults_enabled()
             assert kops.current_impl() == "reference"
         assert kops.current_impl() == "auto"
-        assert not kops.tuned_defaults_enabled()
 
     def test_restores_on_error(self):
         from repro.kernels import ops as kops
@@ -249,22 +285,6 @@ class TestConfigContextManager:
         with pytest.raises(ValueError, match="unknown impl"):
             with api.config(impl="cuda"):
                 pass  # pragma: no cover
-
-    def test_persistent_setter_visible_across_threads(self):
-        """Regression: ServeEngine(autotune=True) sets the tuned-defaults
-        *process-wide* default in __init__; generate() may run on another
-        thread and must still see it (ContextVars alone would not)."""
-        from repro.kernels import ops as kops
-        seen = {}
-        try:
-            kops.set_tuned_defaults(True)
-            th = threading.Thread(
-                target=lambda: seen.update(
-                    tuned=kops.tuned_defaults_enabled()))
-            th.start(); th.join(5)
-            assert seen["tuned"] is True
-        finally:
-            kops.set_tuned_defaults(False)
 
     def test_concurrent_threads_do_not_race(self):
         """The failure mode the satellite targets: an override in one

@@ -12,9 +12,11 @@ import pytest
 
 from repro import obs
 from repro.resilience.failover import _slot_divisor
-from repro.serve import (FailoverPolicy, RetryPolicy, Request, ServicePricer,
-                         SloSpec, SlotPlan, StaticPolicy, Trace, make_faults,
-                         make_trace, simulate)
+from repro.resilience.failover import FailoverPolicy, RetryPolicy
+from repro.resilience.faults import make_faults
+from repro.serve.policies import StaticPolicy
+from repro.serve.sim import ServicePricer, SloSpec, SlotPlan, simulate
+from repro.serve.traffic import Request, Trace, make_trace
 
 PLAN = SlotPlan(n_slots=4, point="1.00GHz@0.80V", batch_max=1)
 SLO = SloSpec(latency_ms=25.0)

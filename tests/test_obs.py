@@ -433,35 +433,6 @@ class TestTraceCli:
 # ---------------------------------------------------------------------------
 
 class TestServeEngine:
-    def test_power_cap_without_autotune_names_both_fixes(self):
-        from repro.serve.engine import ServeEngine
-        with pytest.raises(ValueError, match="autotune=True") as ei:
-            ServeEngine(object(), None, power_cap_mw=5.0)
-        msg = str(ei.value)
-        assert "drop power_cap_mw" in msg
-
-    def test_autotune_records_plan_metrics(self):
-        from repro.kernels import ops as kops
-        from repro.serve.engine import ServeEngine
-        try:
-            with obs.session(trace=False, metrics=True) as sess:
-                eng = ServeEngine(object(), None, autotune=True,
-                                  power_cap_mw=250.0)
-        finally:
-            kops.set_tuned_defaults(False)
-        m = sess.metrics()
-        # The autotune's time is its span's histogram; its plan is read
-        # from the engine, not copied into gauges.
-        hist = m["span.serve.autotune.seconds"]
-        assert hist["type"] == "histogram" and hist["count"] == 1
-        assert hist["total"] > 0
-        assert not [k for k in m if k.startswith(("serve.plan.", "serve.autotune."))]
-        for name in ("softmax", "prng"):
-            cost = eng.operating_plan[name].best_cost
-            assert cost.cycles > 0 and cost.time_ns > 0
-            assert 0 < cost.power_mw <= 250.0
-        assert eng.system_plan is None
-
     @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
     def test_weights_cast_at_build_gauge(self, dtype):
         """``serve.weights.cast_at_build`` holds the bytes of the fp32

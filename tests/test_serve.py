@@ -15,10 +15,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops as kops
-from repro.serve import (POLICIES, ModelPredictivePolicy, PolicyContext,
-                         ReactivePolicy, Request, ServicePricer, SimReport,
-                         SloSpec, SlotPlan, StaticPolicy, Trace, make_trace,
-                         plan_for_rate, simulate)
+from repro.serve.policies import (POLICIES, ModelPredictivePolicy,
+                                  ReactivePolicy, StaticPolicy, plan_for_rate)
+from repro.serve.sim import (PolicyContext, ServicePricer, SimReport, SloSpec,
+                             SlotPlan, simulate)
+from repro.serve.traffic import Request, Trace, make_trace
 from repro.serve.engine import ServeEngine, _mix32, make_cache
 
 
@@ -162,47 +163,6 @@ class TestEngineWorkingWeights:
             else:
                 assert w.dtype == p.dtype
                 np.testing.assert_array_equal(np.asarray(w), b)
-
-
-class TestEngineTunedDefaultScope:
-    def test_autotune_restores_process_default_on_close(self):
-        # Regression: autotune=True flipped kops.set_tuned_defaults(True)
-        # for the whole process and nothing ever undid it.
-        prev = kops.tuned_defaults_enabled()
-        try:
-            eng = _engine(autotune=True)
-            assert kops.tuned_defaults_enabled() is True
-            eng.close()
-            assert kops.tuned_defaults_enabled() == prev
-            eng.close()   # idempotent
-            assert kops.tuned_defaults_enabled() == prev
-        finally:
-            kops.set_tuned_defaults(prev)
-
-    def test_context_manager_scopes_the_flip(self):
-        prev = kops.tuned_defaults_enabled()
-        try:
-            with _engine(autotune=True) as eng:
-                assert eng.operating_plan is not None
-                assert kops.tuned_defaults_enabled() is True
-            assert kops.tuned_defaults_enabled() == prev
-        finally:
-            kops.set_tuned_defaults(prev)
-
-    def test_persist_escape_hatch_survives_close(self):
-        prev = kops.tuned_defaults_enabled()
-        try:
-            eng = _engine(autotune=True, persist_tuned_defaults=True)
-            eng.close()
-            assert kops.tuned_defaults_enabled() is True
-        finally:
-            kops.set_tuned_defaults(prev)
-
-    def test_close_without_autotune_is_a_noop(self):
-        prev = kops.tuned_defaults_enabled()
-        eng = _engine()
-        eng.close()
-        assert kops.tuned_defaults_enabled() == prev
 
 
 class TestEngineSampling:

@@ -211,13 +211,13 @@ class TestTunerClusterCount:
 
 class TestServeSystem:
     def test_pricer_partitions_system_cores(self):
-        from repro.serve import ServicePricer
+        from repro.serve.sim import ServicePricer
         pricer = ServicePricer(system=SystemConfig.homogeneous(
             4, SNITCH_CLUSTER))
         assert pricer.n_cores == 32
 
     def test_nonuniform_system_rejected(self):
-        from repro.serve import ServicePricer
+        from repro.serve.sim import ServicePricer
         mixed = SystemConfig(clusters=(SNITCH_CLUSTER,
                                        SNITCH_CLUSTER.with_cores(4)))
         with pytest.raises(ValueError, match="uniform"):
@@ -227,7 +227,7 @@ class TestServeSystem:
         """A slot spanning k whole clusters prices exactly what the
         facade prices on the equivalent Target.system; a sub-cluster
         slot is bit-for-bit the single-cluster pricer."""
-        from repro.serve import ServicePricer
+        from repro.serve.sim import ServicePricer
         system = SystemConfig.homogeneous(4, SNITCH_CLUSTER)
         pricer = ServicePricer(system=system)
         single = ServicePricer()
@@ -240,8 +240,9 @@ class TestServeSystem:
             == single.price("expf", 65536, 4, pt)
 
     def test_simulate_runs_on_a_system_pricer(self):
-        from repro.serve import ServicePricer, StaticPolicy, make_trace, \
-            simulate
+        from repro.serve.policies import StaticPolicy
+        from repro.serve.sim import ServicePricer, simulate
+        from repro.serve.traffic import make_trace
         pricer = ServicePricer(system=SystemConfig.homogeneous(
             2, SNITCH_CLUSTER))
         tr = make_trace("poisson:rate=400,kernel=softmax,elems=65536",
@@ -257,8 +258,9 @@ class TestSloAwareAdmission:
         SLO-aware gate sheds early and keeps admitted requests within the
         bound — strictly fewer total violations than tail-drop, which
         poisons the queue and lets nearly every completion run late."""
-        from repro.serve import (ServicePricer, SloSpec, SlotPlan,
-                                 StaticPolicy, make_trace, simulate)
+        from repro.serve.policies import StaticPolicy
+        from repro.serve.sim import ServicePricer, SloSpec, SlotPlan, simulate
+        from repro.serve.traffic import make_trace
         pricer = ServicePricer()
         plan = SlotPlan(n_slots=1, point="0.50GHz@0.60V", batch_max=1)
         tr = make_trace("poisson:rate=1500,kernel=softmax,elems=65536",
@@ -276,7 +278,9 @@ class TestSloAwareAdmission:
         assert tail.latency_ms["p99"] > slo.latency_ms
 
     def test_admission_validation(self):
-        from repro.serve import SloSpec, StaticPolicy, make_trace, simulate
+        from repro.serve.policies import StaticPolicy
+        from repro.serve.sim import SloSpec, simulate
+        from repro.serve.traffic import make_trace
         tr = make_trace("poisson:rate=50,kernel=softmax,elems=4096",
                         duration_ms=100.0, seed=1)
         with pytest.raises(ValueError, match="admission"):

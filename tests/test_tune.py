@@ -318,20 +318,6 @@ class TestIntegration:
         assert res.best.movers == get_workload("expf").schedule().n_ssrs
         assert res.best.n_cores == 1
 
-    def test_kernels_tuned_defaults_toggle(self, tmp_path, monkeypatch):
-        monkeypatch.setenv("REPRO_TUNE_CACHE", str(tmp_path / "cache.json"))
-        from repro.kernels import ops as kops
-        rows = kops._resolve_rows("expf", None, 64)
-        assert rows == 64
-        kops.set_tuned_defaults(True)
-        try:
-            tuned = kops._resolve_rows("expf", None, 64)
-            assert 1 <= tuned <= 64
-            assert kops._resolve_rows("expf", 16, 64) == 16
-        finally:
-            kops.set_tuned_defaults(False)
-        assert kops._resolve_rows("expf", None, 64) == 64
-
     def test_tune_bench_generate_contract(self):
         from benchmarks.tune_bench import format_lines, generate
         doc = generate(tiny=True, cluster=False)
